@@ -986,8 +986,7 @@ class GeoDataset:
         )
         exp.kv("prefetch pipeline",
                bool(config.PIPELINE_PREFETCH.to_bool()))
-        exp.kv("persistent compile cache",
-               config.COMPILE_CACHE_DIR.get() or "off")
+        exp.kv("persistent compile cache", kreg.enable_persistent_cache())
         exp.pop()
         # observability posture. The trace_id is THIS explain call's own
         # trace (explain writes no audit event); a query's audit-greppable

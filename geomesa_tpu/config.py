@@ -281,8 +281,9 @@ COMPACT_SHARD_BUCKET = SystemProperty("geomesa.compact.shard.bucket", "8192")
 #: — docs/PERF.md "Registry pressure").
 KERNEL_CACHE_SIZE = SystemProperty("geomesa.kernel.cache.size", "512")
 
-#: Directory for JAX's persistent compilation cache; when set, compiled
-#: XLA executables survive process restarts (warm starts skip compiles).
+#: Directory for JAX's persistent compilation cache, for deployments that
+#: do not set JAX_COMPILATION_CACHE_DIR (which wins); unset, the cache is
+#: <checkout>/.jax_cache (kernels/registry.py enable_persistent_cache).
 COMPILE_CACHE_DIR = SystemProperty("geomesa.compile.cache.dir", None)
 
 #: Double-buffered partition pipeline: overlap the NEXT partition's host

@@ -252,6 +252,10 @@ def density_grid_pairs(x, y, mask, bbox, width: int, height: int, weight,
         else jnp.where(mask, weight.astype(jnp.float32), jnp.float32(0))
     )
     dt = jnp.bfloat16 if weight is None else jnp.float32
+    # f32 operands need HIGHEST: the TPU's default contract precision
+    # rounds them to bf16 (a v5e run put weighted cells 1e-2 off, PR 21);
+    # the per-tile partials of the second einsum are f32 sums either way
+    wprec = None if weight is None else jax.lax.Precision.HIGHEST
     ntiles = ntx * nty
     P = pair_chunk.shape[0]
     ix = jnp.arange(TX, dtype=jnp.int32)[None, None, :]
@@ -267,11 +271,13 @@ def density_grid_pairs(x, y, mask, bbox, width: int, height: int, weight,
         ohx = (lx[:, :, None] == ix).astype(dt)
         A = jnp.where(ly[:, :, None] == iy, gw[:, :, None], 0).astype(dt)
         tile = jnp.einsum(
-            "pby,pbx->pyx", A, ohx, preferred_element_type=jnp.float32
+            "pby,pbx->pyx", A, ohx, precision=wprec,
+            preferred_element_type=jnp.float32,
         )
         oht = (sl(ptile)[:, None] == it).astype(jnp.float32)
         return acc + jnp.einsum(
-            "pt,pyx->tyx", oht, tile, preferred_element_type=jnp.float32
+            "pt,pyx->tyx", oht, tile, precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         )
 
     acc = jax.lax.fori_loop(

@@ -32,9 +32,11 @@ relayout):
 Rows outside the pair's tile produce all-zero one-hot columns
 (clip(1-|dx|, 0, 1) with out-of-range dx), so multi-tile chunks need no
 masking; consecutive steps of one tile accumulate in VMEM and write back
-on tile change (grouped-matmul revisiting). Measured at the bench shape
-(2.2M compact rows, 36k pairs, 512x512 grid): ~9.5 ms vs 15.5 ms scatter
-and ~22 ms einsum.
+on tile change (grouped-matmul revisiting). Schedules longer than
+``SEGMENT`` pairs run as several calls (the pair arrays live in SMEM);
+their per-tile partials add. Timed in r4 through a device plug-in since
+removed (unrecorded): ~9.5 ms vs 15.5 ms scatter and ~22 ms einsum at 36k
+pairs.
 
 Unweighted counts use bfloat16 one-hots (0/1 exact, f32 accumulation);
 weighted densities use f32 operands end-to-end.
@@ -54,6 +56,11 @@ TILE = 128
 #: chunks per superchunk (the fetch granularity; 8 = the minimum legal
 #: sublane block)
 SG = 8
+
+#: pairs per pallas call. The five pair arrays are scalar-prefetched into
+#: SMEM (20 B per pair, 1 MiB on a v5e: the compiler refuses ~52k pairs),
+#: so a longer schedule runs as several calls whose tile partials add
+SEGMENT = 16384
 
 #: pad-pair tile origin: far enough off-grid that every one-hot misses,
 #: small enough that int32 cell arithmetic cannot overflow
@@ -97,8 +104,6 @@ def build_grouped(
     tile = tile[order]
     ox = (tx[order] * TILE).astype(np.int32)
     oy = (ty[order] * TILE).astype(np.int32)
-    seen = np.zeros(ntiles, bool)
-    seen[np.unique(tile)] = True
     # bucket the pair count (shared ladder with the compact chunk count) so
     # similar queries reuse one compiled kernel shape instead of tracing a
     # fresh pallas program per distinct P. Pad pairs aim at the LAST tile
@@ -123,7 +128,6 @@ def build_grouped(
         "tile": tile,
         "ox": ox,
         "oy": oy,
-        "seen": seen,
         "B": B,
         "ntx": ntx,
         "nty": nty,
@@ -132,7 +136,7 @@ def build_grouped(
 
 
 def density_grid_grouped(x, y, mask, bbox, width: int, height: int, weight,
-                         sc, row, tile, ox, oy, seen,
+                         sc, row, tile, ox, oy,
                          B: int, ntx: int, nty: int, n_pairs: int):
     """Device kernel: dense compact [C, B] columns + pair schedule -> grid.
 
@@ -166,6 +170,9 @@ def density_grid_grouped(x, y, mask, bbox, width: int, height: int, weight,
         py = jnp.pad(py, ((0, pad), (0, 0)))
         w = jnp.pad(w, ((0, pad), (0, 0)))
     dt = jnp.bfloat16 if weight is None else jnp.float32
+    # weighted: f32 products on the MXU, stated rather than left to the
+    # compiler's default contract precision
+    precision = None if weight is None else jax.lax.Precision.HIGHEST
     ntiles = ntx * nty
     T = TILE
 
@@ -187,6 +194,7 @@ def density_grid_grouped(x, y, mask, bbox, width: int, height: int, weight,
              * jnp.clip(1 - jnp.abs(dy), 0, 1).astype(dt))
         t = jax.lax.dot_general(
             A, ohx, (((1,), (1,)), ((), ())),
+            precision=precision,
             preferred_element_type=jnp.float32,
         )[None]
 
@@ -198,30 +206,39 @@ def density_grid_grouped(x, y, mask, bbox, width: int, height: int, weight,
         def _():
             acc_ref[...] += t
 
-    acc = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=5,
-            grid=(n_pairs,),
-            in_specs=[
-                pl.BlockSpec(
-                    (SG, B), lambda p, sc, r, t, ox, oy: (sc[p], 0)
+    def call(steps):
+        return pl.pallas_call(
+            kernel,
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(steps,),
+                in_specs=[
+                    pl.BlockSpec(
+                        (SG, B), lambda p, sc, r, t, ox, oy: (sc[p], 0)
+                    ),
+                    pl.BlockSpec(
+                        (SG, B), lambda p, sc, r, t, ox, oy: (sc[p], 0)
+                    ),
+                    pl.BlockSpec(
+                        (SG, B), lambda p, sc, r, t, ox, oy: (sc[p], 0)
+                    ),
+                ],
+                out_specs=pl.BlockSpec(
+                    (1, T, T), lambda p, sc, r, t, ox, oy: (t[p], 0, 0)
                 ),
-                pl.BlockSpec(
-                    (SG, B), lambda p, sc, r, t, ox, oy: (sc[p], 0)
-                ),
-                pl.BlockSpec(
-                    (SG, B), lambda p, sc, r, t, ox, oy: (sc[p], 0)
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, T, T), lambda p, sc, r, t, ox, oy: (t[p], 0, 0)
             ),
-        ),
-        out_shape=jax.ShapeDtypeStruct((ntiles, T, T), jnp.float32),
-        interpret=pk.interpret_mode(),
-    )(sc, row, tile, ox, oy, px, py, w)
-    # blocks never visited hold uninitialized VMEM — zero them via the mask
-    acc = jnp.where(seen[:, None, None], acc, jnp.float32(0))
+            out_shape=jax.ShapeDtypeStruct((ntiles, T, T), jnp.float32),
+            interpret=pk.interpret_mode(),
+            name="density_grouped",
+        )
+
+    acc = None
+    for lo in range(0, n_pairs, SEGMENT):
+        seg = [a[lo:lo + SEGMENT] for a in (sc, row, tile, ox, oy)]
+        part = call(seg[0].shape[0])(*seg, px, py, w)
+        # blocks this call never visited hold uninitialized VMEM
+        seen = jnp.zeros(ntiles, bool).at[seg[2]].set(True)
+        part = jnp.where(seen[:, None, None], part, jnp.float32(0))
+        acc = part if acc is None else acc + part
     grid = acc.reshape(nty, ntx, T, T).transpose(0, 2, 1, 3)
     return grid.reshape(nty * T, ntx * T)[:height, :width]

@@ -7,8 +7,8 @@ jnp. What benefits from a hand kernel is the **point-in-polygon fine filter**
 (the reference's per-row geometry predicate inside AggregatingScan,
 index/iterators/AggregatingScan.scala:82-116): N points x E edges of
 crossing-parity work with an [N, E] broadcast intermediate. The Pallas
-version pins the edge table in VMEM and streams point blocks through the VPU,
-so the [block, E] intermediate never touches HBM.
+version keeps the edge table in SMEM and streams lane-dense point blocks
+through the VPU, one edge per loop step, so no [N, E] intermediate exists.
 
 CPU tests run the same kernel in interpret mode (tests/test_pallas.py);
 production dispatch gates on the TPU backend (``use_pallas()``).
@@ -22,10 +22,13 @@ import threading
 
 import numpy as np
 
-_BLOCK = 1024  # points per program (sublane-aligned: f32 tiles are (8, 128))
-# Edge cap is sized by the kernel's [_BLOCK, Ep] VMEM intermediates (~4 live
-# f32/i32 arrays): 1024 x 1024 x 4 B x 4 = 16 MB, the VMEM budget — not by
-# the 4 x Ep edge table, which is comparatively free.
+_LANES = 128
+#: point rows per program: [_ROWS, 128] f32 blocks (32K points), lane-dense
+#: so HBM holds the points at their own size (a [N, 1] column layout pads
+#: every point to a 128-lane tile row: 128x the bytes)
+_ROWS = 256
+#: edges per polygon the kernel takes: the edge table lives in SMEM
+#: (4 x 1024 f32 = 16 KiB) and the kernel loops over it once per block
 _MAX_EDGES = 1024
 
 _tls = threading.local()
@@ -65,12 +68,9 @@ def _backend_ok() -> bool:
         return False
     if interpret_mode():
         return True
-    try:
-        import jax
+    import jax
 
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def use_pallas() -> bool:
@@ -164,70 +164,78 @@ def pack_edges(x1, y1, y2, slope) -> np.ndarray:
     return out
 
 
-def _pip_kernel(x_ref, y_ref, e_ref, out_ref):
-    """One block of points vs the full edge table (even-odd crossing parity).
-
-    x/y blocks are [B, 1] (column layout so the [B, E] broadcast puts E on
-    the 128-lane axis); the edge table [4, Ep] lives whole in VMEM."""
+def _pip_kernel(e_ref, x_ref, y_ref, out_ref):
+    """One [_ROWS, 128] block of points against every edge (even-odd
+    crossing parity). ``e_ref`` is the [4, E] edge table in SMEM; each loop
+    step reads one edge's scalars and updates the parity of every point."""
+    import jax
     import jax.numpy as jnp
 
-    x = x_ref[:]          # [B, 1]
-    y = y_ref[:]          # [B, 1]
-    x1 = e_ref[0:1, :]    # [1, Ep]
-    y1 = e_ref[1:2, :]
-    y2 = e_ref[2:3, :]
-    slope = e_ref[3:4, :]
-    cond = (y1 > y) != (y2 > y)                      # [B, Ep]
-    xint = x1 + (y - y1) * slope
-    crossings = jnp.sum(
-        (cond & (x < xint)).astype(jnp.int32), axis=1, keepdims=True
+    x = x_ref[...]
+    y = y_ref[...]
+
+    def edge(i, parity):
+        x1, y1, y2, slope = e_ref[0, i], e_ref[1, i], e_ref[2, i], e_ref[3, i]
+        cond = (y1 > y) != (y2 > y)
+        xint = x1 + (y - y1) * slope
+        return parity ^ (cond & (x < xint)).astype(jnp.int32)
+
+    out_ref[...] = jax.lax.fori_loop(
+        0, e_ref.shape[1], edge, jnp.zeros(x.shape, jnp.int32)
     )
-    out_ref[:] = (crossings % 2).astype(jnp.float32)
 
 
 def _pip_call(xf, yf, edges, interpret: bool = False):
+    """Parity of ``[rows, 128]`` point arrays (rows a multiple of _ROWS)."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    n = xf.shape[0]
-    nb = pl.cdiv(n, _BLOCK)
-    col = lambda i: (i, 0)  # noqa: E731
+    rows = xf.shape[0]
+    block = pl.BlockSpec((_ROWS, _LANES), lambda i: (i, 0))
     return pl.pallas_call(
         _pip_kernel,
-        grid=(nb,),
+        grid=(rows // _ROWS,),
         in_specs=[
-            pl.BlockSpec((_BLOCK, 1), col, memory_space=pltpu.VMEM),
-            pl.BlockSpec((_BLOCK, 1), col, memory_space=pltpu.VMEM),
-            pl.BlockSpec(
-                (4, edges.shape[1]), lambda i: (0, 0), memory_space=pltpu.VMEM
-            ),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            block,
+            block,
         ],
-        out_specs=pl.BlockSpec((_BLOCK, 1), col, memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((nb * _BLOCK, 1), jnp.float32),
+        out_specs=block,
+        out_shape=jax.ShapeDtypeStruct((rows, _LANES), jnp.int32),
         interpret=interpret,
-    )(xf.reshape(-1, 1), yf.reshape(-1, 1), edges)
+        name="pip_parity",
+    )(edges, xf, yf)
 
 
 def pip_mask(x, y, edges: np.ndarray, interpret: bool = False):
     """Even-odd point-in-polygon mask for one polygon's packed edge table.
 
     ``x``/``y``: jnp arrays of any shape; returns a bool mask of that shape.
-    Points are zero-padded up to the block size — padding results are sliced
-    off before reshaping back."""
+    Points are zero-padded to whole blocks; padding results are sliced off
+    before reshaping back. Only edges that can be crossed (y1 != y2) enter
+    the kernel: the lane padding of the packed table and horizontal edges
+    never change a parity."""
     import jax.numpy as jnp
 
+    edges = np.asarray(edges, np.float32)
+    edges = edges[:, edges[1] != edges[2]]
+    if edges.shape[1] == 0:
+        return jnp.zeros(x.shape, bool)
     shape = x.shape
     xf = jnp.ravel(x).astype(jnp.float32)
     yf = jnp.ravel(y).astype(jnp.float32)
     n = xf.shape[0]
-    pad = (-n) % _BLOCK
+    pad = (-n) % (_ROWS * _LANES)
     if pad:
         xf = jnp.pad(xf, (0, pad))
         yf = jnp.pad(yf, (0, pad))
-    out = _pip_call(xf, yf, jnp.asarray(edges), interpret=interpret)
-    return out[:n, 0].astype(bool).reshape(shape)
+    out = _pip_call(
+        xf.reshape(-1, _LANES), yf.reshape(-1, _LANES), jnp.asarray(edges),
+        interpret=interpret,
+    )
+    return out.reshape(-1)[:n].astype(bool).reshape(shape)
 
 
 def pip_mask_sharded(x, y, edges: np.ndarray, mesh, interpret: bool = False):
@@ -238,23 +246,16 @@ def pip_mask_sharded(x, y, edges: np.ndarray, mesh, interpret: bool = False):
     to the [N, E] broadcast path. Axes other than 'shard' (e.g. the
     binspace 'bin' axis) see replicated inputs and outputs."""
     import jax
-    import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
     spec = P("shard", None)
 
-    def local(xl, yl, el):
-        return pip_mask(xl, yl, el, interpret=interpret)
+    def local(xl, yl):
+        return pip_mask(xl, yl, edges, interpret=interpret)
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # pre-0.4.35 jax: experimental module
-        from jax.experimental.shard_map import shard_map
-    kw = dict(mesh=mesh, in_specs=(spec, spec, P(None, None)), out_specs=spec)
-    try:
-        sm = shard_map(local, check_vma=False, **kw)
-    except TypeError:  # older jax spells it check_rep
-        sm = shard_map(local, check_rep=False, **kw)
-    return sm(x, y, jnp.asarray(edges))
+    sm = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec),
+                       out_specs=spec, check_vma=False)
+    return sm(x, y)
 
 
 def edges_fit(n_edges: int) -> bool:

@@ -23,8 +23,9 @@ is the executor's warm-path substrate (docs/PERF.md):
   count K to a power of two above a floor, so distinct-but-similar
   queries land on one compiled shape (padded windows are empty and the
   ``valid``/``counts`` masks keep results exact).
-* **persistent compile cache** — :func:`enable_persistent_cache` wires
-  ``jax_compilation_cache_dir`` behind ``geomesa.compile.cache.dir`` so
+* **persistent compile cache** — :func:`enable_persistent_cache` keeps
+  JAX's compilation cache in ``JAX_COMPILATION_CACHE_DIR``, else
+  ``geomesa.compile.cache.dir``, else ``<checkout>/.jax_cache``, so
   restarts start warm.
 
 Metrics (process registry): ``kernel.recompiles`` (fresh traces),
@@ -33,6 +34,7 @@ Metrics (process registry): ``kernel.recompiles`` (fresh traces),
 
 from __future__ import annotations
 
+import os
 import threading
 import time as _time
 from collections import OrderedDict
@@ -299,33 +301,32 @@ def bucket_count(n: int) -> int:
     return max(n, floor)
 
 
-_persistent_cache_done = [False]
+#: the default cache directory: fixed, because the path is part of what
+#: JAX's cache is keyed by (a directory that moves never hits)
+CHECKOUT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))),
+    ".jax_cache",
+)
 _persistent_cache_lock = threading.Lock()
 
 
-def enable_persistent_cache() -> Optional[str]:
-    """Point JAX's persistent compilation cache at
-    ``geomesa.compile.cache.dir`` (idempotent; no-op when unset). With it
-    set, process restarts reuse compiled XLA executables from disk — the
-    cold-start twin of the in-process registry above. Returns the dir in
-    effect (None = disabled)."""
-    d = config.COMPILE_CACHE_DIR.get()
-    if not d:
-        return None
-    with _persistent_cache_lock:
-        if _persistent_cache_done[0]:
-            return d
-        import jax
+def enable_persistent_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its directory.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already uses it and no
+    directory is set here; otherwise a deployment's
+    ``geomesa.compile.cache.dir``; otherwise :data:`CHECKOUT_CACHE_DIR`.
+    Compiles of every size are kept: scan kernels compile fast but are
+    traced often, and the default minimum compile time would skip them."""
+    import jax
 
-        try:
-            jax.config.update("jax_compilation_cache_dir", d)
-            # persist everything: scan kernels compile fast but re-trace
-            # often; the default min-compile-time gate would skip them
+    with _persistent_cache_lock:
+        d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+        if not d:
+            d = config.COMPILE_CACHE_DIR.get() or CHECKOUT_CACHE_DIR
+            if jax.config.jax_compilation_cache_dir != d:
+                jax.config.update("jax_compilation_cache_dir", d)
+        if jax.config.jax_persistent_cache_min_compile_time_secs != 0:
             jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
             jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except AttributeError:
-            # older jax without these knobs: directory option alone still
-            # enables the cache where supported
-            pass
-        _persistent_cache_done[0] = True
     return d

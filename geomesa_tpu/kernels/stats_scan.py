@@ -141,6 +141,25 @@ def decode_enum_keys(stat: sk.Stat, dicts) -> sk.Stat:
     return stat
 
 
+def combine_partials(stat: sk.Stat, a, b):
+    """Merge two :func:`device_update` partials of ``stat`` into one (the
+    exact host partial of the f32 band rows into the device partial of the
+    rest). Returns host arrays."""
+    out = []
+    for leaf, pa, pb in zip(_leaf_stats(stat), a, b):
+        pa = {k: np.asarray(v) for k, v in pa.items()}
+        pb = {k: np.asarray(v) for k, v in pb.items()}
+        if leaf.kind == "minmax":
+            out.append({
+                "count": pa["count"] + pb["count"],
+                "lo": np.minimum(pa["lo"], pb["lo"]),
+                "hi": np.maximum(pa["hi"], pb["hi"]),
+            })
+        else:  # counts and sums add
+            out.append({k: pa[k] + pb[k] for k in pa})
+    return out
+
+
 def absorb_partials(stat: sk.Stat, partials, dicts) -> sk.Stat:
     """Fold device partial states back into host Stat objects."""
     for leaf, p in zip(_leaf_stats(stat), partials):

@@ -7,13 +7,16 @@ function has a NumPy fallback (used when the library is absent or when
 ``GEOMESA_NATIVE=0``), so behavior is identical either way; parity is
 enforced by tests/test_native.py.
 
-The shared library is built lazily with ``g++ -O3 -shared`` the first time it
-is needed (single attempt, guarded by a marker to avoid repeated failures).
+The shared library is built from the tree's source with ``g++ -O3 -shared``
+the first time it is needed (single attempt per process). Its file name
+carries a hash of the source, so a library built from other source, such as
+a stale build lying in a copied checkout, is never loaded.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -23,7 +26,6 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _NATIVE_DIR = os.path.join(os.path.dirname(_HERE), "native")
-_SO_PATH = os.path.join(_NATIVE_DIR, "libgeomesa_native.so")
 _SRC_PATH = os.path.join(_NATIVE_DIR, "geomesa_native.cpp")
 
 _lock = threading.Lock()
@@ -38,18 +40,27 @@ _u8p = np.ctypeslib.ndpointer(np.uint8, flags="C_CONTIGUOUS")
 _f64p = np.ctypeslib.ndpointer(np.float64, flags="C_CONTIGUOUS")
 
 
-def _build() -> bool:
-    """Compile the shared library in-place. Returns success."""
-    if not os.path.exists(_SRC_PATH):
-        return False
+def _so_path() -> Optional[str]:
+    """Where the library built from the current source lives (None when
+    the source is absent)."""
+    try:
+        with open(_SRC_PATH, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    except OSError:
+        return None
+    return os.path.join(_NATIVE_DIR, f"libgeomesa_native-{digest}.so")
+
+
+def _build(so_path: str) -> bool:
+    """Compile the shared library to ``so_path``. Returns success."""
     # build to a temp name and rename: concurrent first-callers (sidecar +
     # CLI, pytest workers) must never dlopen a half-written .so
-    tmp = f"{_SO_PATH}.{os.getpid()}.tmp"
+    tmp = f"{so_path}.{os.getpid()}.tmp"
     base = ["g++", "-O3", "-fPIC", "-std=c++17", "-shared", "-o", tmp, _SRC_PATH]
     for cmd in (base[:1] + ["-fopenmp"] + base[1:], base):  # openmp optional
         try:
             subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-            os.replace(tmp, _SO_PATH)
+            os.replace(tmp, so_path)
             return True
         except Exception:
             try:
@@ -112,18 +123,13 @@ def lib() -> "Optional[ctypes.CDLL]":
         if _tried or _lib is not None:
             return _lib
         _tried = True
-        if not os.path.exists(_SO_PATH) or (
-            os.path.exists(_SRC_PATH)
-            and os.path.getmtime(_SRC_PATH) > os.path.getmtime(_SO_PATH)
-        ):
-            if not _build():
-                return None
+        so_path = _so_path()
+        if so_path is None:
+            return None
+        if not os.path.exists(so_path) and not _build(so_path):
+            return None
         try:
-            candidate = ctypes.CDLL(_SO_PATH)
-            if candidate.gm_abi_version() != 4:
-                # stale .so from an older source tree: rebuild once
-                if _build():
-                    candidate = ctypes.CDLL(_SO_PATH)
+            candidate = ctypes.CDLL(so_path)
             if candidate.gm_abi_version() == 4:
                 _lib = _bind(candidate)
         except (OSError, AttributeError):

@@ -103,27 +103,19 @@ def build_bin_parallel(
                 lambda c: agg_fn(c, jnp.zeros((c[next(iter(c))].shape[0], L), bool), jnp),
                 cols,
             )
-            # newer jax types shard_map carries as varying/manual; the
-            # pcast marks the zeros accordingly. Older jax (no pcast) has
-            # untyped manual values — plain zeros are already correct.
-            pcast = getattr(jax.lax, "pcast", None)
+            # shard_map carries are typed varying over the manual axes:
+            # the pcast marks the zeros accordingly
             init = jax.tree.map(
-                (lambda sd: pcast(jnp.zeros(sd.shape, sd.dtype),
-                                  ("shard", "bin"), to="varying"))
-                if pcast is not None
-                else (lambda sd: jnp.zeros(sd.shape, sd.dtype)),
+                lambda sd: jax.lax.pcast(jnp.zeros(sd.shape, sd.dtype),
+                                         ("shard", "bin"), to="varying"),
                 shapes,
             )
             part, _ = jax.lax.scan(step, init, jnp.arange(stream_chunks))
         # explicit merge over both mesh axes (ICI collectives)
         return jax.tree.map(lambda p: jax.lax.psum(p, ("shard", "bin")), part)
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # older jax: experimental module
-        from jax.experimental.shard_map import shard_map
-
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(

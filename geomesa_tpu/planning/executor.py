@@ -107,7 +107,7 @@ class Executor:
         #: version-stable (a mutation never recompiles — docs/PERF.md);
         #: window/verdict DATA caches stay keyed by ``.version``.
         self.version_source = version_source or store
-        enable_persistent_cache()  # geomesa.compile.cache.dir (idempotent)
+        enable_persistent_cache()  # idempotent
 
     # -- helpers -----------------------------------------------------------
     def _table(self, plan: QueryPlan) -> IndexTable:
@@ -546,10 +546,6 @@ class Executor:
         import jax.numpy as jnp
         from jax.sharding import PartitionSpec as P
 
-        shard_map = getattr(jax, "shard_map", None)
-        if shard_map is None:  # older jax: experimental module
-            from jax.experimental.shard_map import shard_map
-
         B, Cp = d["B"], d["Cp"]
         compiled = plan.compiled
         names = tuple(dict.fromkeys(list(setup["needed"]) + list(agg_cols)))
@@ -582,7 +578,7 @@ class Executor:
                     m = m & ~compiled.band(ccols, jnp)
                 return jax.lax.psum(agg_fn(ccols, m, jnp, *extra), "shard")
 
-            sm = shard_map(
+            sm = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(
                     {k: P("shard", None) for k in col_names},
@@ -1302,7 +1298,7 @@ class Executor:
             setup, bbox, width, height, "_grouped_cache",
             (config.DENSITY_PALLAS_MAX_DUP.to_float(),),
             _dp.build_grouped,
-            ("sc", "row", "tile", "ox", "oy", "seen"),
+            ("sc", "row", "tile", "ox", "oy"),
         )
 
     def _density_pairs(self, plan: QueryPlan, setup, bbox, width, height):
@@ -1331,7 +1327,7 @@ class Executor:
 
     def _run(self, plan: QueryPlan, agg_fn_dev, agg_fn_host, agg_cols=(),
              cache_key=None, additive=False, extra=(), compactable=True,
-             compact_agg=None):
+             compact_agg=None, band_merge=None):
         check_deadline()
         setup = self._scan_setup(plan, agg_cols)
         if setup is None:
@@ -1349,7 +1345,7 @@ class Executor:
         try:
             return self._run_inner(
                 plan, setup, agg_fn_dev, agg_fn_host, agg_cols, cache_key,
-                additive, extra, compactable, compact_agg,
+                additive, extra, compactable, compact_agg, band_merge,
             )
         finally:
             disp = _pk.take_dispatch()
@@ -1358,21 +1354,24 @@ class Executor:
                                     for k, v in disp.items()})
 
     def _run_inner(self, plan, setup, agg_fn_dev, agg_fn_host, agg_cols,
-                   cache_key, additive, extra, compactable, compact_agg):
+                   cache_key, additive, extra, compactable, compact_agg,
+                   band_merge):
         corr = None
         band_rows = 0
         if setup["use_device"] and plan.compiled.band is not None:
             info = self._band_info(plan, setup)
             band_rows = 0 if info is None else len(info)
             if band_rows:
-                if additive and not plan.hints.sampling:
+                if (additive or band_merge) and not plan.hints.sampling:
                     # device aggregates the certain rows; the band rows'
-                    # exact f64 contribution combines additively
+                    # exact f64 contribution merges in (added, or through
+                    # the caller's ``band_merge`` for non-additive partials)
                     corr = self._band_correction(
                         plan, setup, info, agg_fn_host, agg_cols, extra
                     )
                 else:
                     setup["use_device"] = False  # exact host evaluation
+        merge = band_merge or (lambda a, b: a + b)
         if setup["use_device"]:
             if additive:
                 try:
@@ -1382,7 +1381,7 @@ class Executor:
                     if out is not None:
                         self._note(plan, scan="device-binspace",
                                    band_rows=band_rows)
-                        return out if corr is None else out + corr
+                        return out if corr is None else merge(out, corr)
                 except Exception as e:
                     if os.environ.get("GEOMESA_TPU_STRICT_DEVICE"):
                         raise
@@ -1399,7 +1398,7 @@ class Executor:
                     if out is not None:
                         self._note(plan, scan="device-compact-mesh",
                                    band_rows=band_rows)
-                        return out if corr is None else out + corr
+                        return out if corr is None else merge(out, corr)
                 self._maybe_compact(plan, setup, compactable)
                 if setup["compact"] is not None:
                     agg_use, extra_use, ckey = agg_fn_dev, extra, cache_key
@@ -1422,7 +1421,7 @@ class Executor:
                     )
                     self._note(plan, scan="device-padded",
                                band_rows=band_rows)
-                return out if corr is None else out + corr
+                return out if corr is None else merge(out, corr)
             except Exception as e:
                 if os.environ.get("GEOMESA_TPU_STRICT_DEVICE"):
                     raise
@@ -1567,16 +1566,16 @@ class Executor:
                 Bc, n_pairs = gr["B"], gr["n_pairs"]
                 gntx, gnty = gr["ntx"], gr["nty"]
 
-                def gagg(cols, m, xp, sc, row, tile, ox, oy, seen):
+                def gagg(cols, m, xp, sc, row, tile, ox, oy):
                     return kdp.density_grid_grouped(
                         cols[xc], cols[yc], m, bbox, width, height,
                         cols.get(weight) if weight else None,
-                        sc, row, tile, ox, oy, seen,
+                        sc, row, tile, ox, oy,
                         Bc, gntx, gnty, n_pairs,
                     )
 
                 extra = (gr["sc"], gr["row"], gr["tile"], gr["ox"],
-                         gr["oy"], gr["seen"])
+                         gr["oy"])
                 return gagg, extra, ("grouped", n_pairs, Bc, gntx, gnty)
             pr = self._density_pairs(plan, setup, bbox, width, height)
             if pr is None:
@@ -2196,10 +2195,11 @@ class Executor:
     def stats_batch_partials(self, plans, spec, stats):
         """Unsynced batched stats partials: one
         :func:`~geomesa_tpu.kernels.stats_scan.device_update` pytree list
-        per member — or None when ineligible. Stats never take additive
-        band corrections (the serial path reroutes band-bearing scans to
-        the host), so ANY member with surviving band rows makes the batch
-        ineligible here; descriptive leaves are excluded by
+        per member — or None when ineligible. The batch merges no band
+        partials (the serial path merges each band-bearing member's exact
+        host partial into its device one), so ANY member with surviving
+        band rows makes the batch ineligible here; descriptive leaves are
+        excluded by
         :func:`~geomesa_tpu.kernels.stats_scan.batch_supported`."""
         check_deadline()
         if any(not kstats.batch_supported(s) for s in stats):
@@ -2218,7 +2218,7 @@ class Executor:
                 continue
             info = self._band_info(plan, su)
             if info is not None and len(info):
-                return None  # serial would run this member on host
+                return None  # the serial path merges its band partial
 
         def member_agg(m, cols, mm, xp):
             # padded members reuse member 0's structure (same spec text)
@@ -2316,7 +2316,11 @@ class Executor:
         def agg(cols, m, xp):
             return kstats.device_update(stat, cols, m, xp, vocab_sizes)
 
-        return True, self._run(plan, agg, agg, agg_cols)
+        return True, self._run(
+            plan, agg, agg, agg_cols,
+            band_merge=lambda dev, band: kstats.combine_partials(
+                stat, dev, band),
+        )
 
     def stats(self, plan: QueryPlan, stat: sk.Stat) -> sk.Stat:
         supported, partials = self.stats_partials(plan, stat)

@@ -71,7 +71,14 @@ def _grouped_was_built(ds, plan, bbox, width, height):
     return ex._density_grouped(plan, setup, bbox, width, height) is not None
 
 
-def test_grouped_counts_exact(ds_data, force_pallas):
+@pytest.mark.parametrize("segment", [None, 64])
+def test_grouped_counts_exact(ds_data, force_pallas, segment, monkeypatch):
+    """Exact counts, in one pallas call or (``segment``) as several calls
+    whose tile partials add — the path of schedules too long for SMEM."""
+    from geomesa_tpu.kernels import density_pallas as dp
+
+    if segment is not None:
+        monkeypatch.setattr(dp, "SEGMENT", segment)
     ds, data = ds_data
     st, _, plan = ds._plan("t", ECQL)
     grid = ds.density("t", ECQL, bbox=BBOX, width=256, height=256)

@@ -102,3 +102,29 @@ def test_band_exact_on_binspace_mesh():
     q = "BBOX(geom, -100, 30, -80, 40)"
     want = int(((xs >= -100) & (xs <= -80) & (ys >= 30) & (ys <= 40)).sum())
     assert ds.count("t", q) == want
+
+
+@pytest.mark.parametrize("outside", [False, True])
+def test_stats_band_rows_merge_on_device(outside):
+    """Stats with surviving band rows stay on the device: the band rows'
+    exact host partial merges into the device partial (Count and MinMax),
+    instead of the whole scan being recomputed on the host."""
+    eps = 1e-9
+    rng = np.random.default_rng(8)
+    n = 2_000
+    edge = -80.0 + (eps if outside else -eps)  # collides with f32(-80)
+    xs = np.concatenate([rng.uniform(-99, -81, n - 1), [edge]])
+    ys = np.concatenate([rng.uniform(31, 39, n - 1), [35.0]])
+    vs = np.concatenate([rng.uniform(0, 1, n - 1), [7.5]])
+    ds = _mk(xs, ys, vs)
+    q = "BBOX(geom, -100, 30, -80, 40)"
+    keep = (xs >= -100) & (xs <= -80) & (ys >= 30) & (ys <= 40)
+    count, mm = ds.stats("t", "Count();MinMax(v)", q).stats
+    assert count.count == int(keep.sum())
+    # the device reads the Double column at f32; the band row's 7.5 is exact
+    assert np.isclose(mm.lo, vs[keep].min(), rtol=1e-6, atol=0)
+    assert np.isclose(mm.hi, vs[keep].max(), rtol=1e-6, atol=0)
+    assert (mm.hi == 7.5) == (not outside)
+    path = ds.audit.recent(1)[-1].hints["exec_path"]
+    assert path["scan"].startswith("device"), path
+    assert path["band_rows"] == (0 if outside else 1)

@@ -18,6 +18,8 @@ The contract under test:
 These are the tier-1 recompile-regression tests: fast, CPU-only, no TPU.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -100,18 +102,41 @@ def test_kernel_registry_lru_evicts_one_at_a_time():
     assert reg.traces("site_b") == 1
 
 
-def test_persistent_compile_cache_knob(tmp_path_factory):
-    import jax
+@pytest.mark.parametrize("source", ["env", "property", "neither"])
+def test_persistent_compile_cache_knob(source, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and is left to JAX; else the
+    geomesa.compile.cache.dir property; else the fixed <checkout>/.jax_cache.
+    Each case runs in a fresh interpreter: the choice is made against the
+    process's own environment."""
+    import subprocess
+    import sys
 
     from geomesa_tpu.kernels import registry as regmod
 
-    # a session-stable dir: jax keeps writing cache entries here after the
-    # test, so it must outlive a per-test tmp_path
-    d = str(tmp_path_factory.mktemp("xla_cache"))
-    assert regmod.enable_persistent_cache() is None  # unset -> disabled
-    with config.COMPILE_CACHE_DIR.scoped(d):
-        assert regmod.enable_persistent_cache() == d
-    assert jax.config.jax_compilation_cache_dir == d
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "GEOMESA_COMPILE_CACHE_DIR")}
+    want = str(tmp_path / source)
+    if source == "env":
+        env["JAX_COMPILATION_CACHE_DIR"] = want
+    elif source == "property":
+        env["GEOMESA_COMPILE_CACHE_DIR"] = want
+    else:
+        want = regmod.CHECKOUT_CACHE_DIR
+    code = (
+        "import jax\n"
+        "from geomesa_tpu.kernels.registry import enable_persistent_cache\n"
+        "print(enable_persistent_cache())\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+    )
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run(
+        [sys.executable, "-c", code], env=dict(env, JAX_PLATFORMS="cpu"),
+        cwd=repo, capture_output=True, text=True, timeout=120,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert out.stdout.split() == [want, want]
+    assert want.endswith(".jax_cache") == (source == "neither")
 
 
 # ---------------------------------------------------------------------------
