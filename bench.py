@@ -2092,8 +2092,9 @@ def main():
     from geomesa_tpu import utilization as _util
 
     _usnap = _util.snapshot()
-    # per-device attributed busy seconds (the device.busy.<id> gauges'
-    # totals); CPU(-mesh) numbers under --smoke (see the device block).
+    # per-device in-flight seconds, dispatch to result-ready: an upper
+    # bound on device time (the device.busy.<id> gauges' totals);
+    # CPU(-mesh) numbers under --smoke (see the device block).
     _dev_busy = {
         k: v["busy_s"] for k, v in _usnap["devices"].items()
     }

@@ -26,7 +26,9 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from geomesa_tpu import config, metrics, resilience, security, tracing
+from geomesa_tpu import (
+    config, metrics, resilience, security, tracing, utilization,
+)
 from geomesa_tpu.audit import AuditWriter
 from geomesa_tpu.cache import AggregateCache
 from geomesa_tpu.filter import ir, parse_ecql
@@ -194,7 +196,7 @@ def _traced(op: str, speculative: Optional[str] = None):
             from geomesa_tpu.resilience import DeadlineShedError
 
             spec_ok = bool(kw.pop("speculative_ok", False))
-            with tracing.start(op, schema=name):
+            with tracing.start(op, schema=name), utilization.OP_END:
                 # the fallback runs INSIDE the op's root span, so the
                 # speculative audit event carries this trace id — the
                 # degraded answers are exactly the ones operators need
@@ -1627,7 +1629,7 @@ class GeoDataset:
         if members is not None and len(members) != len(bboxes):
             raise ValueError("members must align with bboxes")
         with tracing.start("density_curve_batch", schema=name,
-                           batch=len(bboxes)), \
+                           batch=len(bboxes)), utilization.OP_END, \
                 self.serving.admit("density_curve"):
             st, q, plan = self._plan(name, q)
             default_bbox = None
@@ -1695,7 +1697,7 @@ class GeoDataset:
             for q in queries
         ]
         with tracing.start("density_curve_filter_batch", schema=name,
-                           batch=len(qs)), \
+                           batch=len(qs)), utilization.OP_END, \
                 self.serving.admit("density_curve"):
             st, plans, spec = self._batch_plans(name, qs)
             if spec is None:
@@ -1836,7 +1838,7 @@ class GeoDataset:
         if members is not None and len(members) != len(queries):
             raise ValueError("members must align with queries")
         with tracing.start("count_batch", schema=name,
-                           batch=len(queries)), \
+                           batch=len(queries)), utilization.OP_END, \
                 self.serving.admit("count"):
             st, plans, spec = self._batch_plans(name, queries)
             if spec is None:
@@ -1870,7 +1872,7 @@ class GeoDataset:
         if len(bboxes) != len(queries):
             raise ValueError("bboxes must align with queries")
         with tracing.start("density_batch", schema=name,
-                           batch=len(queries)), \
+                           batch=len(queries)), utilization.OP_END, \
                 self.serving.admit("density"):
             st, plans, spec = self._batch_plans(name, queries)
             if spec is None:
@@ -1915,7 +1917,7 @@ class GeoDataset:
         if members is not None and len(members) != len(queries):
             raise ValueError("members must align with queries")
         with tracing.start("stats_batch", schema=name,
-                           batch=len(queries)), \
+                           batch=len(queries)), utilization.OP_END, \
                 self.serving.admit("stats"):
             stats = [parse_stat(stat_spec) for _ in queries]
             st, plans, spec = self._batch_plans(name, queries)

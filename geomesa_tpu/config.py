@@ -592,8 +592,8 @@ TRACE_EXPORT_BATCH = SystemProperty("geomesa.trace.export.batch", "64")
 # Per-device utilization accounting (utilization.py; docs/OBSERVABILITY.md).
 # ---------------------------------------------------------------------------
 
-#: Trailing window (seconds) over which the ``device.busy.<id>`` and
-#: ``serving.slot.occupancy.<slot>`` gauges compute their busy fraction.
+#: Trailing window (seconds) over which the ``device.busy.<id>`` (in-flight)
+#: and ``serving.slot.occupancy.<slot>`` gauges compute their fraction.
 DEVICE_BUSY_WINDOW = SystemProperty("geomesa.device.busy.window", "60")
 
 # ---------------------------------------------------------------------------

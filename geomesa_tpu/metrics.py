@@ -566,8 +566,10 @@ TRACE_EXPORT_DROPPED = "trace.export.dropped"
 TRACE_EXPORT_FAILED = "trace.export.failed"
 TRACE_EXPORT_BATCHES = "trace.export.batches"
 # Per-device utilization + SLO burn (utilization.py, slo.py):
-#   device.busy.<id>             gauge: busy fraction of device <id> over
-#                                the trailing geomesa.device.busy.window
+#   device.busy.<id>             gauge: in-flight fraction of device <id>
+#                                (dispatch to result-ready, an upper
+#                                bound on device time) over the trailing
+#                                geomesa.device.busy.window
 #   serving.slot.occupancy.<s>   gauge: busy fraction of pool slot <s>
 #   slo.burn.<op>                gauge: fast-window burn rate for the
 #                                geomesa.slo.<op>.p99.ms target

@@ -55,19 +55,42 @@ _SLAB_GATHER_FNS: Dict[int, Any] = {}
 
 
 def _slab_gather_fn(B: int):
-    """jit'd [C]-chunk slab gather (vmapped dynamic_slice of length B)."""
+    """jit'd [C]-chunk slab gather (vmapped dynamic_slice of length B),
+    named ``slab_gather`` in the device trace."""
     fn = _SLAB_GATHER_FNS.get(B)
     if fn is None:
         import jax
 
-        @jax.jit
-        def fn(flat, gstart):
+        def slab_gather(flat, gstart):
             return jax.vmap(
                 lambda s: jax.lax.dynamic_slice(flat, (s,), (B,))
             )(gstart)
 
-        _SLAB_GATHER_FNS[B] = fn
+        fn = _SLAB_GATHER_FNS[B] = jax.jit(slab_gather)
     return fn
+
+
+def _named(fn, name: str):
+    """Give a function about to be jit'd the name its module carries in
+    the device trace (``jit_<name>``)."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
+def _admitted(starts, ends) -> int:
+    """Rows a window set admits."""
+    return int(np.maximum(ends - starts, 0).sum())
+
+
+@contextlib.contextmanager
+def _sync_span():
+    """``scan.sync``: the host reads a device result. On exit the calling
+    thread's dispatch stamps close (utilization: in flight until here)."""
+    try:
+        with tracing.span("scan.sync"):
+            yield
+    finally:
+        utilization.settle()
 
 
 @contextlib.contextmanager
@@ -159,8 +182,12 @@ class Executor:
         hit = cache.get(rkey)
         if hit is not None:
             starts, ends = hit
+            scanned = _admitted(starts, ends)
         else:
-            starts, ends = table.windows(plan.key_plan)
+            with tracing.span("scan.windows") as sp:
+                starts, ends = table.windows(plan.key_plan)
+                scanned = _admitted(starts, ends)
+                sp.set(rows=scanned)
             if len(cache) >= 64:
                 cache.clear()
             cache[rkey] = (starts, ends)
@@ -244,9 +271,7 @@ class Executor:
         # selectivity instrumentation: rows the coarse windows admit vs the
         # table size. The audit event pairs this with `hits` so over-scan
         # (candidates >> matches) is visible per query instead of silent.
-        plan.__dict__["scanned_rows"] = int(
-            np.maximum(ends - starts, 0).sum()
-        )
+        plan.__dict__["scanned_rows"] = scanned
         plan.__dict__["table_rows"] = int(table.n)
         # the partition prefetcher stages exactly this column set for the
         # NEXT partition while this one executes (partitioned_exec.py)
@@ -348,6 +373,13 @@ class Executor:
         if chit is not None:
             setup["compact"] = chit or None
             return
+        with tracing.span("scan.compact") as sp:
+            self._build_compact(plan, setup, table, ccache, ckey, sp)
+
+    def _build_compact(self, plan: QueryPlan, setup, table, ccache, ckey,
+                       sp) -> None:
+        """The descriptor-cache miss of :meth:`_maybe_compact`: candidate
+        windows, the shared-descriptor lookup, the argsort/repeat build."""
         L = setup["L"]
         chosen = self._compact_candidates(plan, setup)
         if chosen is None:
@@ -357,6 +389,10 @@ class Executor:
             return
         starts, ends, B, lens = chosen
         S, K = starts.shape
+        flat_lens = lens.reshape(-1)
+        nc = -(-flat_lens // B)
+        C = int(nc.sum())
+        sp.set(B=int(B), C=C, rows=C * int(B))
         # content-addressed descriptor share (docs/PERF.md "Shared
         # descriptors"): the built descriptor is pure in (resolved window
         # BYTES, B bucket, padded layout), so any other jit site / query
@@ -375,9 +411,6 @@ class Executor:
             ccache[ckey] = shit
             setup["compact"] = shit or None
             return
-        flat_lens = lens.reshape(-1)
-        nc = -(-flat_lens // B)
-        C = int(nc.sum())
         frac = config.COMPACT_FRACTION.to_float()
         if C * B >= table.n * (0.5 if frac is None else frac):
             # windows admit most of the table: compaction can't win
@@ -454,6 +487,12 @@ class Executor:
         hit = cache.get(ckey)
         if hit is not None:
             return hit or None
+        with tracing.span("scan.compact") as sp:
+            return self._build_mesh_compact(plan, setup, D, cache, ckey, sp)
+
+    def _build_mesh_compact(self, plan: QueryPlan, setup, D: int, cache,
+                            ckey, sp):
+        """The descriptor-cache miss of :meth:`_mesh_compact_desc`."""
         table = setup["table"]
         L = setup["L"]
         chosen = self._compact_candidates(plan, setup)
@@ -480,6 +519,7 @@ class Executor:
             flat_lens = lens.reshape(-1)
             nc = -(-flat_lens // B)
             C = int(nc.sum())
+            sp.set(B=int(B), C=C, rows=C * int(B))
             c_dev = nc.reshape(D, Sd * K).sum(axis=1)
             from geomesa_tpu.kernels.density_mxu import ladder8
 
@@ -587,7 +627,8 @@ class Executor:
                 ),
                 out_specs=P(),
             )
-            go = jax.jit(sm)
+            site = str(cache_key[0]) if cache_key else "agg"
+            go = jax.jit(_named(sm, f"compact_mesh_{site}"))
             fn_cache.put(fn_key, go)
         wcache = self.store.__dict__.setdefault("_win_cache", {})
         wkey = ("mesh_win", d["whash"], B, Cp, D, self.store.uid,
@@ -601,8 +642,10 @@ class Executor:
             if len(wcache) >= 64:
                 wcache.clear()
             wcache[wkey] = win
-        metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-        with utilization.device_busy(self._devkey() or 0):
+        with tracing.span("scan.kernel", compact=True, rows=D * Cp * B,
+                          site=str(cache_key[0]) if cache_key else None):
+            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(self._devkey() or 0)
             return go(
                 {k: dev_cols[k] for k in sorted(names)}, *win, tuple(extra)
             )
@@ -646,11 +689,15 @@ class Executor:
         out = (None, None)
         try:
             table = setup["table"]
-            with config.SCAN_RANGES_TARGET.scoped(cover), \
+            with tracing.span("scan.windows.fine") as sp, \
+                    config.SCAN_RANGES_TARGET.scoped(cover), \
                     ksmod.window_cap(cover):
                 fine_kp = table.keyspace.plan(self.store.ft, plan.filter)
                 if fine_kp is not None:
                     out = table.windows(fine_kp)
+                    if sp is not tracing.NOOP:
+                        sp.set(rows=_admitted(*out),
+                               ranges=len(fine_kp.ranges))
         except Exception:
             logging.getLogger(__name__).warning(
                 "fine window resolution failed; using the planner windows",
@@ -683,18 +730,22 @@ class Executor:
                 gather = _slab_gather_fn(B)
                 if len(cache) >= 64:
                     cache.clear()
-                for n in missing:
-                    out[n] = cache[key0 + (n,)] = gather(
-                        full[n].reshape(-1), g
-                    )
+                with tracing.span("scan.gather", columns=len(missing),
+                                  rows=Cp * B):
+                    utilization.dispatched(self._devkey() or 0)
+                    for n in missing:
+                        out[n] = cache[key0 + (n,)] = gather(
+                            full[n].reshape(-1), g
+                        )
         return out
 
     def _device_compact_agg(self, plan: QueryPlan, setup, agg_fn, agg_cols=(),
-                            cache_key=None, extra=()):
+                            cache_key=None, extra=(), site=None):
         """Mask + aggregation in one jit over the compacted [C, B] layout.
         Same caching contract as :meth:`_device_mask_and_agg`; band rows are
         always excised (the compact path only serves the exact device
-        path), their correction is additive host-side."""
+        path), their correction is additive host-side. The jit is named
+        ``compact_<site>`` in the device trace."""
         import jax
         import jax.numpy as jnp
 
@@ -730,9 +781,9 @@ class Executor:
                 fn_key = ("compact", cache_key, B, Cp, sampling, sample_by,
                           sb_mode, sb_off, sb_vocab, sb_buckets)
         go = fn_cache.get(fn_key) if fn_cache is not None else None
+        site = site or (str(cache_key[0]) if cache_key else "agg")
         if go is None:
 
-            @jax.jit
             def go(cols, lo, valid, extra):
                 iota = jnp.arange(B, dtype=jnp.int32)[None, :]
                 m = (iota >= lo[:, None]) & (iota < (lo + valid)[:, None])
@@ -752,6 +803,7 @@ class Executor:
                     m = kmasks.sampling_mask(m, sampling, jnp)
                 return agg_fn(cols, m, jnp, *extra)
 
+            go = jax.jit(_named(go, f"compact_{site}"))
             if fn_cache is not None:
                 fn_cache.put(fn_key, go)
                 self._note(plan, kernel="trace")
@@ -766,10 +818,10 @@ class Executor:
             if len(wcache) >= 64:
                 wcache.clear()
             wcache[wkey] = win
-        with tracing.span("scan.kernel", compact=True,
-                          site=str(cache_key[0]) if cache_key else None), \
-                utilization.device_busy(self._devkey() or 0):
+        with tracing.span("scan.kernel", compact=True, site=site,
+                          rows=Cp * B):
             metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(self._devkey() or 0)
             return go(cols, win[0], win[1], tuple(extra))
 
     def _expand_compact_mask(self, setup, cmask) -> np.ndarray:
@@ -1009,7 +1061,7 @@ class Executor:
 
     def _device_mask_and_agg(self, plan: QueryPlan, setup, agg_fn, agg_cols=(),
                              cache_key=None, apply_sampling=True, extra=(),
-                             excise_band=True):
+                             excise_band=True, site=None):
         """Run mask + aggregation in one jit. ``agg_fn(cols, mask, xp,
         *extra)`` — ``extra`` values are TRACED jit arguments (scalar query
         parameters like a kNN origin), so one compiled kernel serves every
@@ -1068,9 +1120,9 @@ class Executor:
                           sb_off, sb_vocab, sb_buckets)
             self._note(plan, shape_bucket=(L, K))
         go = fn_cache.get(fn_key) if fn_cache is not None else None
+        site = site or (str(cache_key[0]) if cache_key else "agg")
         if go is None:
 
-            @jax.jit
             def go(cols, starts, ends, counts, extra):
                 m = kmasks.window_mask(starts, ends, counts, L)
                 m = m & compiled(cols, jnp)
@@ -1093,6 +1145,7 @@ class Executor:
                     m = kmasks.sampling_mask(m, sampling, jnp)
                 return agg_fn(cols, m, jnp, *extra)
 
+            go = jax.jit(_named(go, f"padded_{site}"))
             if fn_cache is not None:
                 fn_cache.put(fn_key, go)
                 self._note(plan, kernel="trace")
@@ -1134,15 +1187,14 @@ class Executor:
         # re-dispatch through an inner shard_map over the mesh (bare
         # pallas_call has no GSPMD partitioning rule)
         with pk.sharded_execution(self.mesh), \
-                tracing.span("scan.kernel",
-                             site=str(cache_key[0]) if cache_key else None), \
-                utilization.device_busy(self._devkey() or 0):
+                tracing.span("scan.kernel", site=site,
+                             rows=int(table.n_shards) * L):
             # one observable unit of device work (the serving bench's
             # fusion-actually-fused gate counts these; docs/SERVING.md).
-            # The busy interval covers dispatch (async backends may still
-            # be executing past it) and feeds the device.busy.<id> gauge
-            # plus the per-query device_ms cost attribution.
+            # The stamp stays open until the host holds the result (the
+            # device.busy.<id> gauge and the per-query device_ms cost).
             metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(self._devkey() or 0)
             return go(dev_cols, d_starts, d_ends, d_counts, tuple(extra))
 
     def _sharding(self):
@@ -1246,8 +1298,11 @@ class Executor:
                 mesh, sorted(dev_cols), L, predicate, agg_fn, stream
             )
             cache.put(key, fn)
-        metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-        with utilization.device_busy(self._devkey() or 0):
+        with tracing.span("scan.kernel", site=str(cache_key[0])
+                          if cache_key else None,
+                          rows=int(table.n_shards) * L):
+            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(self._devkey() or 0)
             return fn(
                 {k: dev_cols[k] for k in sorted(dev_cols)},
                 jax.device_put(starts.astype(np.int32), win_sh),
@@ -1256,11 +1311,13 @@ class Executor:
             )
 
     def _cached_density_schedule(self, setup, bbox, width, height,
-                                 cache_name, key_extras, build, device_keys):
+                                 cache_name, key_extras, build, device_keys,
+                                 kernel):
         """Shared cache host for the host-built density pair schedules
         (pallas grouped / MXU einsum): build once per (windows, grid,
         store version, device pin), device_put the array members, remember
-        a False sentinel for negative results."""
+        a False sentinel for negative results. A build runs in a
+        ``scan.schedule`` span."""
         d = setup["compact"]
         table = setup["table"]
         cache = self.store.__dict__.setdefault(cache_name, {})
@@ -1269,16 +1326,18 @@ class Executor:
                    self.store.uid, self.store.version, self._devkey())
         hit = cache.get(key)
         if hit is None:
-            pr = build(
-                d, table, table.keyspace, bbox, width, height,
-                box_cache=self.store.__dict__.setdefault(
-                    "_chunk_box_cache", {}
-                ),
-                version=self.store.version,
-            )
-            if pr is not None:
-                for k in device_keys:
-                    pr[k] = self._put(pr[k])
+            with tracing.span("scan.schedule", kernel=kernel) as sp:
+                pr = build(
+                    d, table, table.keyspace, bbox, width, height,
+                    box_cache=self.store.__dict__.setdefault(
+                        "_chunk_box_cache", {}
+                    ),
+                    version=self.store.version,
+                )
+                if pr is not None:
+                    sp.set(pairs=int(pr["n_pairs"]))
+                    for k in device_keys:
+                        pr[k] = self._put(pr[k])
             if len(cache) >= 64:
                 cache.clear()
             hit = cache[key] = pr if pr is not None else False
@@ -1299,6 +1358,7 @@ class Executor:
             (config.DENSITY_PALLAS_MAX_DUP.to_float(),),
             _dp.build_grouped,
             ("sc", "row", "tile", "ox", "oy"),
+            "grouped",
         )
 
     def _density_pairs(self, plan: QueryPlan, setup, bbox, width, height):
@@ -1314,6 +1374,7 @@ class Executor:
             (_dm.tile_shape(),),
             _dm.build_pairs,
             ("chunk", "px0", "py0", "tile", "pvalid"),
+            "mxu",
         )
 
     @staticmethod
@@ -1327,7 +1388,7 @@ class Executor:
 
     def _run(self, plan: QueryPlan, agg_fn_dev, agg_fn_host, agg_cols=(),
              cache_key=None, additive=False, extra=(), compactable=True,
-             compact_agg=None, band_merge=None):
+             compact_agg=None, band_merge=None, site=None):
         check_deadline()
         setup = self._scan_setup(plan, agg_cols)
         if setup is None:
@@ -1345,7 +1406,7 @@ class Executor:
         try:
             return self._run_inner(
                 plan, setup, agg_fn_dev, agg_fn_host, agg_cols, cache_key,
-                additive, extra, compactable, compact_agg, band_merge,
+                additive, extra, compactable, compact_agg, band_merge, site,
             )
         finally:
             disp = _pk.take_dispatch()
@@ -1355,7 +1416,7 @@ class Executor:
 
     def _run_inner(self, plan, setup, agg_fn_dev, agg_fn_host, agg_cols,
                    cache_key, additive, extra, compactable, compact_agg,
-                   band_merge):
+                   band_merge, site):
         corr = None
         band_rows = 0
         if setup["use_device"] and plan.compiled.band is not None:
@@ -1410,14 +1471,14 @@ class Executor:
                             ckey = (cache_key or ()) + suffix
                     out = self._device_compact_agg(
                         plan, setup, agg_use, agg_cols, ckey,
-                        extra=extra_use,
+                        extra=extra_use, site=site,
                     )
                     self._note(plan, scan="device-compact",
                                B=setup["compact"]["B"], band_rows=band_rows)
                 else:
                     out = self._device_mask_and_agg(
                         plan, setup, agg_fn_dev, agg_cols, cache_key,
-                        extra=extra,
+                        extra=extra, site=site,
                     )
                     self._note(plan, scan="device-padded",
                                band_rows=band_rows)
@@ -1470,7 +1531,7 @@ class Executor:
         out = self.count_partial(plan)
         if out is None:
             return 0
-        with tracing.span("scan.sync"):
+        with _sync_span():
             return int(out)
 
     def features(self, plan: QueryPlan) -> ColumnBatch:
@@ -1491,14 +1552,14 @@ class Executor:
                         plan, setup, lambda cols, m, xp: m,
                         cache_key=("mask",),
                     )
-                    with tracing.span("scan.sync"):
+                    with _sync_span():
                         mask = self._expand_compact_mask(setup, cmask)
                 else:
                     dmask = self._device_mask_and_agg(
                         plan, setup, lambda cols, m, xp: m,
                         cache_key=("mask",),
                     )
-                    with tracing.span("scan.sync"):
+                    with _sync_span():
                         mask = np.asarray(dmask)
             except Exception as e:
                 if os.environ.get("GEOMESA_TPU_STRICT_DEVICE"):
@@ -1608,7 +1669,7 @@ class Executor:
             return np.zeros((height, width), np.float32)
         if not as_numpy:
             return out
-        with tracing.span("scan.sync"):
+        with _sync_span():
             return np.asarray(out)
 
     # -- curve-aligned density (the index-native heatmap) ------------------
@@ -2059,11 +2120,12 @@ class Executor:
             wcache[wkey] = win
         for p in plans:
             self._note(p, scan="device-batch", batch=len(plans))
-        with tracing.span("scan.kernel", site=site, batch=len(plans)), \
-                utilization.device_busy(self._devkey() or 0):
+        with tracing.span("scan.kernel", site=site, batch=len(plans),
+                          rows=int(table.n_shards) * L):
             # ONE observable unit of device work for the whole batch —
             # the distinct-fusion bench/CI gate counts these
             metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(self._devkey() or 0)
             return go(dev_cols, *win, spec.lits_f, spec.lits_i,
                       tuple(extra_arrays))
 
@@ -2320,13 +2382,15 @@ class Executor:
             plan, agg, agg, agg_cols,
             band_merge=lambda dev, band: kstats.combine_partials(
                 stat, dev, band),
+            site="stats",
         )
 
     def stats(self, plan: QueryPlan, stat: sk.Stat) -> sk.Stat:
         supported, partials = self.stats_partials(plan, stat)
         if supported:
             if partials is not None:
-                kstats.absorb_partials(stat, partials, self.store.dicts)
+                with _sync_span():
+                    kstats.absorb_partials(stat, partials, self.store.dicts)
             return stat
         batch = self.features(plan)
         if batch.n:

@@ -743,12 +743,13 @@ def _run_slice(plan: JoinPlan, lo: int, hi: int, lx32, ly32, rx32, ry32,
         if dev is not None:
             ops = tuple(jax.device_put(o, dev) for o in ops)
         with tracing.span("scan.join.pairs", tiles=C, device=getattr(
-                dev, "id", None)), \
-                utilization.device_busy(getattr(dev, "id", 0) or 0):
+                dev, "id", None)):
             metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(getattr(dev, "id", 0) or 0)
             m, counts = go(*ops)
         m = np.asarray(m)
         counts = np.asarray(counts)
+        utilization.settle()
     else:
         m = kjoin.pair_mask(
             lxb[:, :, None], lyb[:, :, None],
@@ -806,12 +807,13 @@ def _run_brute_slice(plan: JoinPlan, lo: int, hi: int, lx32, ly32,
         if dev is not None:
             ops = tuple(jax.device_put(o, dev) for o in ops)
         with tracing.span("scan.join.brute", pairs=K, device=getattr(
-                dev, "id", None)), \
-                utilization.device_busy(getattr(dev, "id", 0) or 0):
+                dev, "id", None)):
             metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(getattr(dev, "id", 0) or 0)
             m, n = go(*ops)
         m = np.asarray(m)
         n = int(n)
+        utilization.settle()
     else:
         m = kjoin.pair_mask(lxv, lyv, rxv, ryv, plan.predicate,
                             plan.p0, plan.p1, np, lz=lzv, rz=rzv)
@@ -1135,10 +1137,11 @@ def _run_poly_slice(rows: np.ndarray, px32, py32, tables, predicate: str,
         if dev is not None:
             ops = tuple(jax.device_put(o, dev) for o in ops)
         with tracing.span("scan.join.poly", points=K, device=getattr(
-                dev, "id", None)), \
-                utilization.device_busy(getattr(dev, "id", 0) or 0):
+                dev, "id", None)):
             metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+            utilization.dispatched(getattr(dev, "id", 0) or 0)
             verdict = np.asarray(go(*ops))
+        utilization.settle()
     else:
         verdict = kjoin.polygon_mask(pxv, pyv, tables, predicate, np)
     return verdict[:K]

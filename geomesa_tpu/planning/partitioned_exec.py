@@ -32,7 +32,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 
 import numpy as np
 
-from geomesa_tpu import config, metrics, resilience, tracing
+from geomesa_tpu import config, metrics, resilience, tracing, utilization
 from geomesa_tpu.parallel import health as phealth
 from geomesa_tpu.filter import ir
 from geomesa_tpu.index.partitioned import PartitionedFeatureStore
@@ -47,6 +47,17 @@ from geomesa_tpu.stats import sketches as sk
 
 _SKIPPED = object()  # sentinel: partition degraded away (fn may return None)
 _UNSET = object()
+
+
+def _synced(finish):
+    """A partition ``finish`` that reads its partial on the host: the
+    partition's dispatch stamps close after the read (utilization.py). A
+    finish that keeps the merge on the device (density) is left
+    unwrapped, and its stamps close with the operation."""
+    def read(b, p, mdev):
+        finish(b, p, mdev)
+        utilization.settle()
+    return read
 
 
 def _coalesce_boxes(boxes: List[Tuple[float, float, float, float]]
@@ -558,11 +569,14 @@ class PartitionedExecutor:
         feeds the device's latency-outlier detector (a straggler lane is
         fenced like a failing one — parallel/health.py). Dispatch
         failures requeue the partition onto surviving devices
-        (:meth:`_dispatch_reassign`)."""
+        (:meth:`_dispatch_reassign`). Each partition's dispatch stamps
+        (utilization.py) ride with its partial and are open again when
+        its finish runs (:func:`_synced`)."""
         metrics.inc(metrics.SCAN_SHARDED)
         from collections import deque
 
-        pending: "deque" = deque()  # (bin, partial, device) awaiting finish
+        # (bin, partial, device, shape, stamps) awaiting finish
+        pending: "deque" = deque()
         mdev = devs[0]  # the device the serial path computes on
         hreg = phealth.registry()
         #: devices still surviving THIS scan (failed lanes drop out and
@@ -570,7 +584,7 @@ class PartitionedExecutor:
         live: List = list(devs)
 
         def _finish_oldest():
-            fb, fr, fdev, fshape = pending.popleft()
+            fb, fr, fdev, fshape, stamps = pending.popleft()
             t0 = time.perf_counter()
 
             def _fin():
@@ -591,6 +605,7 @@ class PartitionedExecutor:
                     hreg.record_success(fdev.id)
                 return out
 
+            utilization.attach(stamps)
             self._scan_part(plan, fb, op, _fin,
                             probe=False, spanned=False)
             if fdev is not None:
@@ -629,7 +644,7 @@ class PartitionedExecutor:
                     # compiled kernel shape)
                     lbucket = config.SHARD_LEN_BUCKET.to_int() or 65536
                     shape = (op, -(-child.count // max(lbucket, 1)))
-                    pending.append((b, r, dev, shape))
+                    pending.append((b, r, dev, shape, utilization.detach()))
                 # dispatched work holds its own buffer references: staged
                 # host arrays and evicted children free safely here even
                 # while the device is still executing
@@ -804,7 +819,7 @@ class PartitionedExecutor:
         totals: List[int] = []
         self._additive_scan(
             plan, "count", lambda ex: ex.count_partial(plan),
-            lambda b, p, mdev: totals.append(int(p)),
+            _synced(lambda b, p, mdev: totals.append(int(p))),
             push=True,
         )
         return sum(totals)
@@ -852,7 +867,7 @@ class PartitionedExecutor:
             plan, "density_curve",
             lambda ex: ex.density_curve_raw(plan, level, block_window,
                                             weight),
-            lambda b, p, mdev: red.push(Executor.decode_curve(p)),
+            _synced(lambda b, p, mdev: red.push(Executor.decode_curve(p))),
             push=weight is None,  # see density: integer block counts only
         )
         out = red.result()
@@ -879,7 +894,9 @@ class PartitionedExecutor:
             lambda ex: ex.density_curve_batch_raw(
                 plan, level, block_windows, weight
             ),
-            lambda b, p, mdev: red.push(Executor.decode_curve_batch(p)),
+            _synced(
+                lambda b, p, mdev: red.push(Executor.decode_curve_batch(p))
+            ),
         )
         merged = red.result()
         outs = []
@@ -930,8 +947,8 @@ class PartitionedExecutor:
             else:
                 red.push(Executor.decode_curve_filter_batch(p))
 
-        self._additive_scan(plans[0], "density_curve", dispatch, finish,
-                            bins=bins)
+        self._additive_scan(plans[0], "density_curve", dispatch,
+                            _synced(finish), bins=bins)
         merged = red.result()
         outs = []
         for i, (ix0, iy0, ix1, iy1) in enumerate(block_windows):
@@ -1006,7 +1023,7 @@ class PartitionedExecutor:
 
         self._additive_scan(
             carrier, "count", dispatch,
-            finish, bins=bins,
+            _synced(finish), bins=bins,
         )
         return totals
 
@@ -1037,7 +1054,7 @@ class PartitionedExecutor:
 
         self._additive_scan(
             plans[0], "density", dispatch,
-            finish, bins=bins,
+            _synced(finish), bins=bins,
         )
         merged = red.result()
         if merged is None:
@@ -1075,7 +1092,7 @@ class PartitionedExecutor:
             return r
 
         self._additive_scan(
-            plans[0], "stats", dispatch, finish,
+            plans[0], "stats", dispatch, _synced(finish),
             bins=bins,
         )
         if saw_ineligible[0]:
@@ -1103,9 +1120,9 @@ class PartitionedExecutor:
             self._additive_scan(
                 plan, "stats",
                 lambda ex: ex.stats_partials(plan, stat)[1],
-                lambda b, p, mdev: kstats.absorb_partials(
+                _synced(lambda b, p, mdev: kstats.absorb_partials(
                     stat, p, self.store.dicts
-                ),
+                )),
                 push=True,  # sketches observe only matching rows
             )
             return stat

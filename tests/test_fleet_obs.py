@@ -47,11 +47,13 @@ def _mk_ds(n=4000, partitioned=False, seed=9, n_shards=2):
 
 def test_busy_fraction_window_math(monkeypatch):
     utilization.reset()
-    now = [1000.0]
+    now = [998.0]
     monkeypatch.setattr(utilization, "_clock", lambda: now[0])
     with config.DEVICE_BUSY_WINDOW.scoped("10"):
-        # 2s busy ending at t=1000 -> fraction 0.2 over the 10s window
-        utilization.record_device(3, 2.0)
+        # 2s in flight ending at t=1000 -> fraction 0.2 over the 10s window
+        utilization.dispatched(3)
+        now[0] = 1000.0
+        utilization.settle()
         frac = utilization.snapshot()["devices"]["3"]["busy_fraction"]
         assert frac == pytest.approx(0.2, abs=1e-6)
         # window start (999) bisects the interval: 1 of its 2 busy
@@ -64,24 +66,129 @@ def test_busy_fraction_window_math(monkeypatch):
         assert u.fraction() == 0.0
         # totals never roll: the cumulative busy_s survives the window
         assert u.busy_s == pytest.approx(2.0)
-        # overlapping concurrent intervals clamp at 1.0
-        utilization.record_device(4, 8.0)
-        utilization.record_device(4, 8.0)
-        assert utilization._devices[4].fraction() == 1.0
+        # overlapping slot intervals (summed host brackets) clamp at 1.0
+        utilization.record_slot(4, 8.0)
+        utilization.record_slot(4, 8.0)
+        assert utilization._slots[4].fraction() == 1.0
 
 
 def test_device_busy_feeds_gauge_and_trace_cost():
     utilization.reset()
     with config.TRACE_ENABLED.scoped("true"):
         with tracing.start("op_cost_test"):
-            with utilization.device_busy(6):
-                pass
+            utilization.dispatched(6)
+            utilization.settle()
             cost = tracing.current_cost()
     assert "device_ms.6" in cost
     g = metrics.registry().gauge(f"{metrics.DEVICE_BUSY_PREFIX}.6")
     assert 0.0 <= g.value <= 1.0
     snap = utilization.snapshot()
     assert snap["devices"]["6"]["intervals"] == 1
+    assert cost["device_ms.6"] == pytest.approx(
+        snap["devices"]["6"]["busy_s"] * 1e3, rel=1e-6, abs=1e-3)
+
+
+def test_overlapping_partition_settles_cost_the_union(monkeypatch):
+    """Two partitions in flight on one device at once, each settled at its
+    own read: the query's device_ms is the gauge's union, not the sum."""
+    utilization.reset()
+    now = [10.0]
+    monkeypatch.setattr(utilization, "_clock", lambda: now[0])
+    with config.TRACE_ENABLED.scoped("true"), \
+            config.DEVICE_BUSY_WINDOW.scoped("60"):
+        with tracing.start("op_union_test"):
+            utilization.dispatched(2)
+            first = utilization.detach()
+            now[0] = 11.0
+            utilization.dispatched(2)
+            second = utilization.detach()
+            now[0] = 14.0
+            utilization.settle(first)    # [10, 14]
+            now[0] = 16.0
+            utilization.settle(second)   # [11, 16]: only [14, 16] is new
+            cost = tracing.current_cost()
+    assert utilization._devices[2].busy_s == pytest.approx(6.0)
+    assert cost["device_ms.2"] == pytest.approx(6000.0)
+
+
+def test_in_flight_interval_covers_the_sync_wait(monkeypatch):
+    """A dispatch whose result the host reads later: the device's interval
+    runs from the dispatch stamp to the read, not around the enqueue."""
+    utilization.reset()
+    now = [100.0]
+    monkeypatch.setattr(utilization, "_clock", lambda: now[0])
+    with config.DEVICE_BUSY_WINDOW.scoped("60"):
+        utilization.dispatched(5)
+        now[0] = 100.01   # the enqueue call returns
+        now[0] = 103.0    # the host blocks on the result until here
+        utilization.settle()
+        u = utilization._devices[5]
+        assert u.busy_s == pytest.approx(3.0)
+        assert u.fraction() == pytest.approx(3.0 / 60)
+        utilization.settle()   # nothing open: no second interval
+        assert u.count == 1
+
+
+def test_overlapping_in_flight_dispatches_count_as_union(monkeypatch):
+    utilization.reset()
+    now = [10.0]
+    monkeypatch.setattr(utilization, "_clock", lambda: now[0])
+    with config.DEVICE_BUSY_WINDOW.scoped("60"):
+        # two pipelined dispatches on one device, one read: [10, 14]
+        utilization.dispatched(7)
+        now[0] = 11.0
+        utilization.dispatched(7)
+        now[0] = 14.0
+        utilization.settle()
+        assert utilization._devices[7].busy_s == pytest.approx(4.0)
+        # a partition dispatched at 12 (as another lane would have, so the
+        # clock steps back), carried to its own merge and read at 16: it
+        # overlaps [10, 14], so only [14, 16] is new
+        now[0] = 12.0
+        utilization.dispatched(7)
+        carried = utilization.detach()
+        assert carried == [(7, 12.0)]
+        now[0] = 16.0
+        utilization.settle(carried)
+        u = utilization._devices[7]
+        assert u.busy_s == pytest.approx(6.0)
+        assert u.fraction() == pytest.approx(6.0 / 60)
+        # another device is its own union
+        utilization.dispatched(8)
+        now[0] = 17.0
+        utilization.settle()
+        assert utilization._devices[8].busy_s == pytest.approx(1.0)
+        assert u.busy_s == pytest.approx(6.0)
+
+
+def test_query_in_flight_time_covers_its_sync_span():
+    """A real device query: the in-flight time the cost ledger and the
+    device gauge get runs from dispatch through the scan.sync read."""
+    utilization.reset()
+    ds = _mk_ds(4000)
+    with config.TRACE_ENABLED.scoped("true"):
+        ds.density("t", BBOX, bbox=(-100, 30, -80, 45), width=64,
+                   height=64)
+        tr = tracing.last_trace()
+    tree = tr.root.to_dict()
+
+    def find(t, name):
+        if t["name"] == name:
+            return t
+        for c in t.get("children", ()):
+            hit = find(c, name)
+            if hit is not None:
+                return hit
+        return None
+
+    sync = find(tree, "scan.sync")
+    kernel = find(tree, "scan.kernel")
+    assert sync is not None and kernel is not None
+    cost = tr.cost["device_ms.0"]
+    assert cost >= sync["ms"] * 0.999
+    assert cost <= tree["ms"] * 1.001
+    assert utilization.snapshot()["devices"]["0"]["busy_s"] == \
+        pytest.approx(cost / 1e3, rel=1e-6, abs=1e-6)
 
 
 def test_sharded_scan_attributes_busy_time_across_devices(tmp_path):

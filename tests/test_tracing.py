@@ -463,3 +463,103 @@ def test_cli_trace_and_metrics(tmp_path, capsys):
     out = capsys.readouterr().out
     assert rc == 0
     assert "geomesa_" in out
+
+
+# ---------------------------------------------------------------------------
+# executor phase spans (cold view) and the stats sync span
+# ---------------------------------------------------------------------------
+
+PHASE_Q = ("BBOX(geom, -100, 30, -90, 35) AND dtg DURING "
+           "2020-01-05T00:00:00Z/2020-01-20T00:00:00Z")
+MISS_SPANS = ("scan.windows", "scan.windows.fine", "scan.compact",
+              "scan.schedule", "scan.gather")
+
+
+def _spans(tree, acc=None):
+    acc = [] if acc is None else acc
+    acc.append(tree)
+    for c in tree.get("children", ()):
+        _spans(c, acc)
+    return acc
+
+
+def _by_name(tree):
+    out = {}
+    for s in _spans(tree):
+        out.setdefault(s["name"], []).append(s)
+    return out
+
+
+@pytest.fixture()
+def compact_on():
+    with config.COMPACT_MIN_ROWS.scoped("0"):
+        yield
+
+
+def test_cold_compact_density_has_phase_spans(traced, compact_on):
+    from geomesa_tpu.kernels.density_mxu import ladder8
+
+    ds = _mk_ds(100_000)
+    ds.density("t", PHASE_Q, bbox=(-100, 30, -90, 35), width=32, height=32)
+    tree = tracing.last_trace().root.to_dict()
+    assert ds.audit.recent(1)[0].hints["exec_path"]["scan"] == \
+        "device-compact"
+    spans = _by_name(tree)
+    for name in MISS_SPANS:
+        assert name in spans, (name, sorted(spans))
+    win = spans["scan.windows"][0]["attrs"]
+    assert win["rows"] > 0
+    fine = spans["scan.windows.fine"][0]["attrs"]
+    assert fine["rows"] > 0 and fine["ranges"] > 0
+    # the fine cover runs inside the compaction that chose between the sets
+    compact = spans["scan.compact"][0]
+    assert [c["name"] for c in compact.get("children", ())] == \
+        ["scan.windows.fine"]
+    B, C = compact["attrs"]["B"], compact["attrs"]["C"]
+    assert compact["attrs"]["rows"] == C * B > 0
+    sched = spans["scan.schedule"][0]["attrs"]
+    assert sched["kernel"] in ("grouped", "mxu") and sched["pairs"] > 0
+    # the gather is a child of the device_put that stages the slabs
+    put = [s for s in spans["scan.device_put"]
+           if any(c["name"] == "scan.gather" for c in s.get("children", ()))]
+    assert put, spans["scan.device_put"]
+    gather = spans["scan.gather"][0]["attrs"]
+    rows = ladder8(C) * B
+    assert gather["columns"] >= 2 and gather["rows"] == rows
+    kernel = spans["scan.kernel"][0]["attrs"]
+    assert kernel["rows"] == rows and kernel["site"] == "density"
+    for s in _spans(tree):
+        for v in (s.get("attrs") or {}).values():
+            assert isinstance(v, (int, str)), s
+
+
+def test_warm_repeat_opens_no_miss_path_spans(traced, compact_on):
+    ds = _mk_ds(100_000)
+    for _ in range(2):
+        ds.density("t", PHASE_Q, bbox=(-100, 30, -90, 35), width=32,
+                   height=32)
+    spans = _by_name(tracing.last_trace().root.to_dict())
+    for name in MISS_SPANS:
+        assert name not in spans, name
+    assert "scan.kernel" in spans and "scan.sync" in spans
+
+
+def test_padded_kernel_span_reads_every_stored_row(traced):
+    ds = _mk_ds(5000, n_shards=2)
+    ds.count("t", BBOX)
+    ev = ds.audit.recent(1)[0]
+    assert ev.hints["exec_path"]["scan"] == "device-padded"
+    spans = _by_name(tracing.last_trace().root.to_dict())
+    rows = spans["scan.kernel"][0]["attrs"]["rows"]
+    assert rows in {t.n_shards * t.shard_len
+                    for t in ds._store("t").tables.values()}
+    assert rows >= 5000
+
+
+def test_stats_query_has_sync_span(traced, compact_on):
+    ds = _mk_ds(100_000)
+    ds.stats("t", "Count();MinMax(weight)", PHASE_Q)
+    spans = _by_name(tracing.last_trace().root.to_dict())
+    assert "scan.kernel" in spans
+    assert spans["scan.kernel"][0]["attrs"]["site"] == "stats"
+    assert "scan.sync" in spans
