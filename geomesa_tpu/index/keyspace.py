@@ -238,6 +238,17 @@ def _shift_of(shard_cols: Dict, col: str) -> int:
     return 0 if shifts is None else shifts.get(col, 0)
 
 
+def _bin_segments(bins_col: np.ndarray, bins) -> Tuple[np.ndarray, np.ndarray]:
+    """Each time bin's [start, end) row segment of a (bin, key)-sorted shard,
+    in one vectorized lookup per side. The needles take the column's own
+    dtype: a Python-int needle makes numpy cast the whole column per call."""
+    needles = np.asarray(bins).astype(bins_col.dtype, copy=False)
+    return (
+        np.searchsorted(bins_col, needles, side="left"),
+        np.searchsorted(bins_col, needles, side="right"),
+    )
+
+
 def _coverage(ranges: List[ZRange], total_bits: int) -> float:
     span = sum(r.hi - r.lo + 1 for r in ranges)
     return span / float(1 << total_bits)
@@ -356,54 +367,54 @@ class Z3KeySpace(KeySpace):
         # L-shaped query geometries admit only their own candidates, not
         # the [zmin, zmax] envelope. Edge bins use their time-tightened
         # range sets from plan time. The shifted+merged range sets are
-        # shard-independent: computed once per (plan, shift) and cached.
+        # shard-independent: computed once per (plan, shift) and cached as
+        # search needles in the key column's dtype.
         edge = getattr(plan, "_edge", {})
         cap = shard_window_cap()
         per_bin_cap = max(1, cap // max(len(bins), 1))
         cache = plan.__dict__.setdefault("_shifted_ranges", {})
         sets = cache.get((sh, cap))
         if sets is None:
-            base = _merge_zranges(
-                [(r.lo >> sh, r.hi >> sh) for r in plan.ranges], per_bin_cap
-            )
-            esets = {
-                b: _merge_zranges(
+
+            def needles(rs):
+                merged = _merge_zranges(
                     [(lo >> sh, hi >> sh) for lo, hi in rs], per_bin_cap
                 )
-                for b, rs in edge.items()
-            }
-            sets = cache[(sh, cap)] = (base, esets)
-        base, esets = sets
-        from geomesa_tpu import native
+                return (
+                    np.asarray([r[0] for r in merged], z_col.dtype),
+                    np.asarray([r[1] for r in merged], z_col.dtype),
+                )
 
-        starts: List[int] = []
-        ends: List[int] = []
-        plain = np.asarray(
-            [b for b in bins.tolist() if b not in esets], np.int32
+            sets = cache[(sh, cap)] = (
+                needles((r.lo, r.hi) for r in plan.ranges),
+                {b: needles(rs) for b, rs in edge.items()},
+            )
+        base, esets = sets
+        # Cost per bin, not per range: one lookup finds every bin's segment,
+        # then each segment takes all of its range bounds in one search per
+        # side. The windows are disjoint (disjoint ranges within a bin,
+        # disjoint segments across bins), so the order collected is moot.
+        plain = bins[~np.isin(bins, list(esets))]
+        seg_lo, seg_hi = _bin_segments(
+            bins_col, np.concatenate((plain, np.fromiter(esets, np.int64)))
         )
-        for lo, hi in base:
-            ws, we = native.bin_windows(bins_col, z_col, plain, lo, hi)
-            starts.extend(ws.tolist())
-            ends.extend(we.tolist())
-        for b, rs in esets.items():
-            s = int(np.searchsorted(bins_col, b, side="left"))
-            e = int(np.searchsorted(bins_col, b, side="right"))
-            if e <= s or not rs:
+        sets_per_bin = [base] * len(plain) + list(esets.values())
+        starts, ends = [], []
+        for (los, his), s, e in zip(
+            sets_per_bin, seg_lo.tolist(), seg_hi.tolist()
+        ):
+            if e <= s or not len(los):
                 continue
             seg = z_col[s:e]
-            los = np.asarray([r[0] for r in rs], seg.dtype)
-            his = np.asarray([r[1] for r in rs], seg.dtype)
             ws = s + np.searchsorted(seg, los, side="left")
             we = s + np.searchsorted(seg, his, side="right")
             keep = we > ws
-            starts.extend(ws[keep].tolist())
-            ends.extend(we[keep].tolist())
-        if not starts:
+            starts.append(ws[keep])
+            ends.append(we[keep])
+        starts = np.concatenate(starts) if starts else np.zeros(0, np.int64)
+        if not len(starts):
             return np.zeros(1, np.int64), np.zeros(1, np.int64)
-        return _cap_windows(
-            np.asarray(starts, np.int64), np.asarray(ends, np.int64),
-            shard_window_cap(),
-        )
+        return _cap_windows(starts, np.concatenate(ends), cap)
 
 
 class Z2KeySpace(KeySpace):
@@ -616,9 +627,8 @@ class XZ3KeySpace(KeySpace):
             e = np.searchsorted(bins_col, bins[-1], side="right")
             return np.asarray([s], np.int64), np.asarray([e], np.int64)
         starts, ends = [], []
-        for b in bins.tolist():
-            s = np.searchsorted(bins_col, b, side="left")
-            e = np.searchsorted(bins_col, b, side="right")
+        seg_lo, seg_hi = _bin_segments(bins_col, bins)
+        for s, e in zip(seg_lo.tolist(), seg_hi.tolist()):
             if e <= s:
                 continue
             seg = code_col[s:e]
@@ -774,9 +784,8 @@ class S3KeySpace(KeySpace):
             e = np.searchsorted(bins_col, bins[-1], side="right")
             return np.asarray([s], np.int64), np.asarray([e], np.int64)
         starts, ends = [], []
-        for b in bins.tolist():
-            s = np.searchsorted(bins_col, b, side="left")
-            e = np.searchsorted(bins_col, b, side="right")
+        seg_lo, seg_hi = _bin_segments(bins_col, bins)
+        for s, e in zip(seg_lo.tolist(), seg_hi.tolist()):
             if e <= s:
                 continue
             seg = col[s:e]
