@@ -40,7 +40,7 @@ from geomesa_tpu.stats import sketches as sk
 _HOST_ONLY_DTYPES = ("O", "U", "S")
 
 
-def _device_view(a: np.ndarray) -> Optional[np.ndarray]:
+def device_view(a: np.ndarray) -> Optional[np.ndarray]:
     """Host column -> device-eligible array (int32/float32/bool), or None."""
     if a.dtype.kind in _HOST_ONLY_DTYPES:
         return None
@@ -213,6 +213,13 @@ class IndexTable:
             return col
         return self._master[name][self.order]
 
+    def col_sorted_at(self, name: str, pos: np.ndarray) -> np.ndarray:
+        """``col_sorted(name)[pos]`` without gathering the whole column."""
+        col = self.key_columns.get(name)
+        if col is not None:
+            return col[pos]
+        return self._master[name][self.order[pos]]
+
     def shard_cols(self, names, s: int) -> Dict[str, np.ndarray]:
         """Selected columns for one shard, in sorted order."""
         sl = self.shard_slice(s)
@@ -270,7 +277,7 @@ class IndexTable:
         pad half of a device upload) — pure numpy, no jax."""
         if not self.has_column(name):
             return None
-        dv = _device_view(self.col_sorted(name))
+        dv = device_view(self.col_sorted(name))
         if dv is None:
             return None
         stacked = np.zeros((self.n_shards, L), dtype=dv.dtype)

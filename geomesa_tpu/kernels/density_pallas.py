@@ -44,11 +44,9 @@ weighted densities use f32 operands end-to-end.
 
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Dict
 
 import numpy as np
-
-from geomesa_tpu.kernels.density_mxu import pair_candidates
 
 #: fixed tile = the MXU native shape
 TILE = 128
@@ -67,32 +65,21 @@ SEGMENT = 16384
 _OFFGRID = np.int32(1 << 20)
 
 
-def build_grouped(
-    compact: Dict, table, keyspace, bbox, width: int, height: int,
-    box_cache: Optional[Dict] = None, version=None,
-) -> Optional[Dict]:
-    """Host-side pair schedule for the grouped kernel: (superchunk, tile)
-    pairs sorted by tile id, one pallas grid step per pair. Returns None
-    when the index has no morton key (scatter fallback) or the pair
-    expansion would duplicate rows beyond the configured budget."""
+def max_dup() -> float:
+    """The pair budget: the grouped kernel serves a view only when its
+    (chunk, tile) pairs are at most this many times its real chunks."""
     from geomesa_tpu import config
 
-    cand = pair_candidates(
-        compact, table, keyspace, bbox, width, height, TILE, TILE,
-        box_cache, version,
-    )
-    if cand is None:
-        return None
-    B = compact["B"]
-    # budget against the REAL chunk count: len(valid) is the ladder8-padded
-    # count, which would loosen the configured budget by up to ~25%
-    C = int((compact["valid"] > 0).sum())
-    P = cand["P"]
     md = config.DENSITY_PALLAS_MAX_DUP.to_float()
-    max_dup = 4.0 if md is None else md
-    if C == 0 or P > max_dup * C:
-        return None  # coarse keys made chunk boxes span too many tiles
-    ntx, nty = cand["ntx"], cand["nty"]
+    return 4.0 if md is None else md
+
+
+def build_grouped(cand: Dict, B: int) -> Dict:
+    """Host-side pair schedule for the grouped kernel from the view's
+    ``TILE``-square candidates (``pair_candidates``): (superchunk, tile)
+    pairs sorted by tile id, one pallas grid step per pair. The caller
+    has checked the pair budget (:func:`max_dup`)."""
+    ntx, nty, P = cand["ntx"], cand["nty"], cand["P"]
     ntiles = ntx * nty
     chunk_of, tx, ty = cand["chunk_of"], cand["tx"], cand["ty"]
     tile = (ty * ntx + tx).astype(np.int32)

@@ -621,6 +621,11 @@ SERVING_PLACEMENT_DEFER = "serving.placement.defer"
 SERVING_FUSION_BATCH = "serving.fusion.batch"
 SERVING_EXECUTOR_DISPATCH = "serving.executor.dispatch"
 EXEC_DEVICE_DISPATCH = "exec.device.dispatch"
+#   exec.density.kernel.<kernel>  density dispatches by the kernel of the
+#                           density ladder that served them: grouped (the
+#                           pallas kernel), mxu (the XLA einsum pair
+#                           kernel) or scatter (planning/executor.py)
+EXEC_DENSITY_KERNEL = "exec.density.kernel"
 #: fused batch-size histogram buckets (members per micro-batch)
 FUSION_BATCH_BUCKETS = (1.0, 2.0, 3.0, 4.0, 6.0, 8.0, 12.0, 16.0, 24.0, 32.0)
 # Stream-consumer lag (stream/live.py, stream/confluent.py;

@@ -19,7 +19,7 @@ from typing import Any, Dict, Optional
 import numpy as np
 
 from geomesa_tpu import config, metrics, tracing, utilization
-from geomesa_tpu.index.store import FeatureStore, IndexTable
+from geomesa_tpu.index.store import FeatureStore, IndexTable, device_view
 from geomesa_tpu.kernels import density as kdensity
 from geomesa_tpu.kernels import knn as kknn
 from geomesa_tpu.kernels import masks as kmasks
@@ -642,10 +642,8 @@ class Executor:
             if len(wcache) >= 64:
                 wcache.clear()
             wcache[wkey] = win
-        with tracing.span("scan.kernel", compact=True, rows=D * Cp * B,
-                          site=str(cache_key[0]) if cache_key else None):
-            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-            utilization.dispatched(self._devkey() or 0)
+        with self._kernel_span(str(cache_key[0]) if cache_key else None,
+                               setup, compact=True, rows=D * Cp * B):
             return go(
                 {k: dev_cols[k] for k in sorted(names)}, *win, tuple(extra)
             )
@@ -818,10 +816,7 @@ class Executor:
             if len(wcache) >= 64:
                 wcache.clear()
             wcache[wkey] = win
-        with tracing.span("scan.kernel", compact=True, site=site,
-                          rows=Cp * B):
-            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-            utilization.dispatched(self._devkey() or 0)
+        with self._kernel_span(site, setup, compact=True, rows=Cp * B):
             return go(cols, win[0], win[1], tuple(extra))
 
     def _expand_compact_mask(self, setup, cmask) -> np.ndarray:
@@ -880,12 +875,13 @@ class Executor:
         """f32-uncertainty resolution for the device path. The device
         kernel always runs on ``mask ∧ ¬band`` (band rows excised), which
         is exact for every non-band row. This host pass — one vectorized
-        sweep per (plan token, store version), cached — finds the band
-        rows inside the scan windows, evaluates the EXACT f64 predicate on
-        them, and returns the kept rows' master indices (usually an empty
-        array: at 20M uniform doubles a round query bound collides with
-        ~2-3 rows). Additive aggregates add these rows' contribution to
-        the device partial; other ops fall back when any survive."""
+        sweep over the rows the scan windows admit, per (plan token, store
+        version, windows), cached — finds the band rows among them,
+        evaluates the EXACT f64 predicate on those, and returns the kept
+        rows' sorted-order positions (usually an empty array: at 20M
+        uniform doubles a round query bound collides with ~2-3 rows).
+        Additive aggregates add these rows' contribution to the device
+        partial; other ops fall back when any survive."""
         compiled = plan.compiled
         if compiled.band is None:
             return None
@@ -908,26 +904,25 @@ class Executor:
         names = list(dict.fromkeys(
             list(compiled.columns) + list(compiled.refine_columns or [])
         ))
-        full = {
-            n: table.col_sorted(n) for n in names if table.has_column(n)
+        # sorted-order positions of the admitted rows (the windows of a
+        # shard are disjoint), not the whole table: a cold view's first
+        # visit would otherwise gather every column of every row
+        starts = setup["starts"] + table.shard_bounds[:-1, None]
+        lens = np.maximum(setup["ends"] - setup["starts"], 0).reshape(-1)
+        first = np.cumsum(lens) - lens
+        pos = (np.repeat(starts.reshape(-1) - first, lens)
+               + np.arange(int(lens.sum())))
+        cols = {
+            n: table.col_sorted_at(n, pos) for n in names
+            if table.has_column(n)
         }
-        band = np.asarray(compiled.band(full, np)).reshape(-1)
-        idx = np.nonzero(band)[0]
+        band = np.broadcast_to(
+            np.asarray(compiled.band(cols, np)).reshape(-1), pos.shape
+        )
+        order = np.argsort(pos[band])
+        idx = pos[band][order]
         if len(idx):
-            # inside the scan windows? (vectorized: [n_band, K] broadcast —
-            # equality predicates can band millions of rows)
-            s_of = np.clip(
-                np.searchsorted(table.shard_bounds, idx, side="right") - 1,
-                0, table.n_shards - 1,
-            )
-            local = (idx - table.shard_bounds[s_of])[:, None]
-            starts, ends = setup["starts"], setup["ends"]
-            inw = (
-                (starts[s_of] <= local) & (local < ends[s_of])
-            ).any(axis=1)
-            idx = idx[inw]
-        if len(idx):
-            rows = {n: v[idx] for n, v in full.items()}
+            rows = {n: v[band][order] for n, v in cols.items()}
             # master columns for names stored only via the permutation
             keep = np.asarray(
                 (compiled.refine or compiled.fn)(rows, np)
@@ -956,9 +951,16 @@ class Executor:
         for n in names:
             kc = table.key_columns.get(n)
             if kc is not None:
-                rows[n] = kc[info][None, :]
+                v = kc[info]
             elif table.has_column(n):
-                rows[n] = table._master[n][master_rows][None, :]
+                v = table._master[n][master_rows]
+            else:
+                continue
+            # membership was decided exactly; the aggregate reads the row
+            # as the kernel reads every certain row (f32 coordinates), so
+            # an edge row lands in the density cell the device would bin
+            dv = device_view(v)
+            rows[n] = (v if dv is None else dv)[None, :]
         mask = np.ones((1, len(info)), bool)
         return agg_fn_host(rows, mask, np, *extra)
 
@@ -1187,14 +1189,8 @@ class Executor:
         # re-dispatch through an inner shard_map over the mesh (bare
         # pallas_call has no GSPMD partitioning rule)
         with pk.sharded_execution(self.mesh), \
-                tracing.span("scan.kernel", site=site,
-                             rows=int(table.n_shards) * L):
-            # one observable unit of device work (the serving bench's
-            # fusion-actually-fused gate counts these; docs/SERVING.md).
-            # The stamp stays open until the host holds the result (the
-            # device.busy.<id> gauge and the per-query device_ms cost).
-            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-            utilization.dispatched(self._devkey() or 0)
+                self._kernel_span(site, setup,
+                                  rows=int(table.n_shards) * L):
             return go(dev_cols, d_starts, d_ends, d_counts, tuple(extra))
 
     def _sharding(self):
@@ -1298,11 +1294,8 @@ class Executor:
                 mesh, sorted(dev_cols), L, predicate, agg_fn, stream
             )
             cache.put(key, fn)
-        with tracing.span("scan.kernel", site=str(cache_key[0])
-                          if cache_key else None,
-                          rows=int(table.n_shards) * L):
-            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-            utilization.dispatched(self._devkey() or 0)
+        with self._kernel_span(str(cache_key[0]) if cache_key else None,
+                               setup, rows=int(table.n_shards) * L):
             return fn(
                 {k: dev_cols[k] for k in sorted(dev_cols)},
                 jax.device_put(starts.astype(np.int32), win_sh),
@@ -1310,72 +1303,77 @@ class Executor:
                 jax.device_put(setup["counts"].astype(np.int32), cnt_sh),
             )
 
-    def _cached_density_schedule(self, setup, bbox, width, height,
-                                 cache_name, key_extras, build, device_keys,
-                                 kernel):
-        """Shared cache host for the host-built density pair schedules
-        (pallas grouped / MXU einsum): build once per (windows, grid,
-        store version, device pin), device_put the array members, remember
-        a False sentinel for negative results. A build runs in a
-        ``scan.schedule`` span."""
-        d = setup["compact"]
-        table = setup["table"]
-        cache = self.store.__dict__.setdefault(cache_name, {})
-        key = (cache_name, d["whash"], tuple(bbox), width, height, d["B"],
-               d["C"]) + tuple(key_extras) + (
-                   self.store.uid, self.store.version, self._devkey())
-        hit = cache.get(key)
-        if hit is None:
-            with tracing.span("scan.schedule", kernel=kernel) as sp:
-                pr = build(
-                    d, table, table.keyspace, bbox, width, height,
-                    box_cache=self.store.__dict__.setdefault(
-                        "_chunk_box_cache", {}
-                    ),
-                    version=self.store.version,
-                )
-                if pr is not None:
-                    sp.set(pairs=int(pr["n_pairs"]))
-                    for k in device_keys:
-                        pr[k] = self._put(pr[k])
-            if len(cache) >= 64:
-                cache.clear()
-            hit = cache[key] = pr if pr is not None else False
-        return hit or None
-
-    def _density_grouped(self, plan: QueryPlan, setup, bbox, width, height):
-        """Pair schedule for the pallas grouped density kernel, cached on
-        device per (windows, grid, store version). None when pallas is
-        unavailable, the kernel is disabled, or the index has no morton
-        key (callers fall through to the einsum/scatter paths)."""
+    def _density_ladder(self, setup, bbox, width, height):
+        """The density kernel ladder's choice for one view of the compact
+        layout: ``(kernel, schedule, P, C)``. ``grouped`` (the pallas
+        kernel) when pallas runs here and the view's ``TILE``-square
+        (chunk, tile) candidates P are within ``max_dup`` times its real
+        chunks C; else ``mxu`` (the XLA einsum pair kernel); else
+        ``scatter``, with no schedule (also when the index has no morton
+        key). Built on the host and its arrays placed on the device once
+        per (windows, grid, store version, device pin, ladder options), in
+        a ``scan.schedule`` span; a cache hit returns the same P and C."""
+        from geomesa_tpu.kernels import density_mxu as _dm
         from geomesa_tpu.kernels import density_pallas as _dp
         from geomesa_tpu.kernels import pallas_kernels as pk
 
-        if not config.DENSITY_PALLAS.to_bool() or not pk.use_pallas():
-            return None
-        return self._cached_density_schedule(
-            setup, bbox, width, height, "_grouped_cache",
-            (config.DENSITY_PALLAS_MAX_DUP.to_float(),),
-            _dp.build_grouped,
-            ("sc", "row", "tile", "ox", "oy"),
-            "grouped",
-        )
+        d = setup["compact"]
+        table = setup["table"]
+        pallas = config.DENSITY_PALLAS.to_bool() and pk.use_pallas()
+        mxu = config.DENSITY_MXU.to_bool()
+        cache = self.store.__dict__.setdefault("_density_ladder_cache", {})
+        key = (d["whash"], tuple(bbox), width, height, d["B"], d["C"],
+               pallas, mxu, _dp.max_dup(), _dm.tile_shape(),
+               self.store.uid, self.store.version, self._devkey())
+        hit = cache.get(key)
+        if hit is not None:
+            return hit
+        with tracing.span("scan.schedule") as sp:
+            boxes = self.store.__dict__.setdefault("_chunk_box_cache", {})
+            args = (d, table, table.keyspace, bbox, width, height)
+            cand = _dm.pair_candidates(*args, _dp.TILE, _dp.TILE, boxes,
+                                       self.store.version)
+            # budget against the REAL chunk count: len(valid) is the
+            # ladder8-padded count, which would loosen it by up to ~25%
+            C = int((d["valid"] > 0).sum())
+            P = 0 if cand is None else int(cand["P"])
+            kernel, sched, put = "scatter", None, ()
+            if cand is not None and pallas and P <= _dp.max_dup() * C:
+                kernel, sched = "grouped", _dp.build_grouped(cand, d["B"])
+                put = ("sc", "row", "tile", "ox", "oy")
+            elif cand is not None and mxu:
+                sched = _dm.build_pairs(*args, box_cache=boxes,
+                                        version=self.store.version)
+                if sched is not None:
+                    kernel = "mxu"
+                    put = ("chunk", "px0", "py0", "tile", "pvalid")
+            for k in put:
+                sched[k] = self._put(sched[k])
+            sp.set(kernel=kernel, pairs=P, chunks=C)
+        if len(cache) >= 64:
+            cache.clear()
+        hit = cache[key] = (kernel, sched, P, C)
+        return hit
 
-    def _density_pairs(self, plan: QueryPlan, setup, bbox, width, height):
-        """(chunk, tile) pair arrays for the MXU density kernel, cached on
-        device per (windows, grid, store version). None when the index has
-        no morton key or the kernel is disabled."""
-        from geomesa_tpu.kernels import density_mxu as _dm
-
-        if not config.DENSITY_MXU.to_bool():
-            return None
-        return self._cached_density_schedule(
-            setup, bbox, width, height, "_pair_cache",
-            (_dm.tile_shape(),),
-            _dm.build_pairs,
-            ("chunk", "px0", "py0", "tile", "pvalid"),
-            "mxu",
-        )
+    def _kernel_span(self, site, setup=None, **attrs):
+        """The ``scan.kernel`` span of one executor kernel dispatch, after
+        counting it (``exec.device.dispatch``, one observable unit of
+        device work that the serving fusion gate counts; docs/SERVING.md)
+        and opening its device busy stamp, which stays open until the host
+        holds the result (the device.busy.<id> gauge and the per-query
+        device_ms cost). A density dispatch also counts
+        ``exec.density.kernel.<kernel>`` and names on its span the ladder's
+        ``density_kernel`` with the ``pairs`` and ``chunks`` its budget
+        tested (``scatter``, 0, 0 where the ladder did not run: the padded,
+        mesh and fused batch paths)."""
+        metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
+        utilization.dispatched(self._devkey() or 0)
+        if site in ("density", "density_batch"):
+            kernel, pairs, chunks = ((setup or {}).get("density_kernel")
+                                     or ("scatter", 0, 0))
+            metrics.inc(f"{metrics.EXEC_DENSITY_KERNEL}.{kernel}")
+            attrs.update(density_kernel=kernel, pairs=pairs, chunks=chunks)
+        return tracing.span("scan.kernel", site=site, **attrs)
 
     @staticmethod
     def _note(plan: QueryPlan, **kw) -> None:
@@ -1615,17 +1613,18 @@ class Executor:
 
         def mxu_agg(setup):
             # device kernel ladder over the compacted layout: pallas
-            # grouped one-hot matmul (kernels/density_pallas.py) when the
-            # backend has pallas, else the XLA einsum pair kernel
-            # (kernels/density_mxu.py), else the scatter agg (returns
-            # None when the index has no morton key column)
-            gr = self._density_grouped(plan, setup, bbox, width, height)
-            if gr is not None:
+            # grouped one-hot matmul (kernels/density_pallas.py), the XLA
+            # einsum pair kernel (kernels/density_mxu.py), or the scatter
+            # agg (None)
+            kernel, sched, P, C = self._density_ladder(setup, bbox, width,
+                                                       height)
+            setup["density_kernel"] = (kernel, P, C)
+            if kernel == "grouped":
                 self._note(plan, density_kernel="pallas-grouped-mxu")
                 from geomesa_tpu.kernels import density_pallas as kdp
 
-                Bc, n_pairs = gr["B"], gr["n_pairs"]
-                gntx, gnty = gr["ntx"], gr["nty"]
+                Bc, n_pairs = sched["B"], sched["n_pairs"]
+                gntx, gnty = sched["ntx"], sched["nty"]
 
                 def gagg(cols, m, xp, sc, row, tile, ox, oy):
                     return kdp.density_grid_grouped(
@@ -1635,18 +1634,17 @@ class Executor:
                         Bc, gntx, gnty, n_pairs,
                     )
 
-                extra = (gr["sc"], gr["row"], gr["tile"], gr["ox"],
-                         gr["oy"])
+                extra = tuple(sched[k] for k in ("sc", "row", "tile", "ox",
+                                                 "oy"))
                 return gagg, extra, ("grouped", n_pairs, Bc, gntx, gnty)
-            pr = self._density_pairs(plan, setup, bbox, width, height)
-            if pr is None:
+            if kernel == "scatter":
                 self._note(plan, density_kernel="scatter")
                 return None
             self._note(plan, density_kernel="mxu-einsum")
             from geomesa_tpu.kernels import density_mxu as kmxu
 
-            PB, ntx, nty = pr["PB"], pr["ntx"], pr["nty"]
-            TY, TX = pr["TY"], pr["TX"]
+            PB, ntx, nty = sched["PB"], sched["ntx"], sched["nty"]
+            TY, TX = sched["TY"], sched["TX"]
 
             def pagg(cols, m, xp, pc, p0, p1, pt, pv):
                 return kmxu.density_grid_pairs(
@@ -1655,9 +1653,9 @@ class Executor:
                     pc, p0, p1, pt, pv, PB, ntx, nty, TY, TX, xp,
                 )
 
-            extra = (pr["chunk"], pr["px0"], pr["py0"], pr["tile"],
-                     pr["pvalid"])
-            return pagg, extra, ("mxu", pr["P"], PB, TX, TY)
+            extra = tuple(sched[k] for k in ("chunk", "px0", "py0", "tile",
+                                             "pvalid"))
+            return pagg, extra, ("mxu", sched["P"], PB, TX, TY)
 
         out = self._run(
             plan, agg, agg, agg_cols,
@@ -2120,12 +2118,10 @@ class Executor:
             wcache[wkey] = win
         for p in plans:
             self._note(p, scan="device-batch", batch=len(plans))
-        with tracing.span("scan.kernel", site=site, batch=len(plans),
-                          rows=int(table.n_shards) * L):
-            # ONE observable unit of device work for the whole batch —
-            # the distinct-fusion bench/CI gate counts these
-            metrics.inc(metrics.EXEC_DEVICE_DISPATCH)
-            utilization.dispatched(self._devkey() or 0)
+        # ONE observable unit of device work for the whole batch — the
+        # distinct-fusion bench/CI gate counts these
+        with self._kernel_span(site, batch=len(plans),
+                               rows=int(table.n_shards) * L):
             return go(dev_cols, *win, spec.lits_f, spec.lits_i,
                       tuple(extra_arrays))
 
@@ -2380,6 +2376,10 @@ class Executor:
 
         return True, self._run(
             plan, agg, agg, agg_cols,
+            # the stat STRUCTURE is baked into the traced update: it keys
+            # the kernel, so a repeat of the query compiles nothing
+            cache_key=("stats", self._stat_signature(stat),
+                       tuple(sorted(vocab_sizes.items()))),
             band_merge=lambda dev, band: kstats.combine_partials(
                 stat, dev, band),
             site="stats",

@@ -134,9 +134,10 @@ def test_mxu_density_per_cell(ds_data, force_compact):
     st, _, plan = ds._plan("t", ECQL)
     ex = ds._executor(st)
     grid = ex.density(plan, bbox, W, H)
-    # pair cache must hold a real pair list (proves the MXU path ran)
-    pc = st.__dict__.get("_pair_cache", {})
-    assert any(v for v in pc.values()), "MXU pair path did not engage"
+    # the ladder must have chosen the MXU pair list (proves the MXU path ran)
+    pc = st.__dict__.get("_density_ladder_cache", {})
+    assert any(v[0] == "mxu" for v in pc.values()), \
+        "MXU pair path did not engage"
     m = _oracle_mask(data)
     want = _f32_hist(data["geom__x"][m], data["geom__y"][m], bbox, W, H)
     np.testing.assert_allclose(grid, want)

@@ -68,7 +68,7 @@ def _grouped_was_built(ds, plan, bbox, width, height):
     ex._maybe_compact(plan, setup, True)
     if setup["compact"] is None:
         return False
-    return ex._density_grouped(plan, setup, bbox, width, height) is not None
+    return ex._density_ladder(setup, bbox, width, height)[0] == "grouped"
 
 
 @pytest.mark.parametrize("segment", [None, 64])
