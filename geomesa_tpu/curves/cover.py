@@ -21,10 +21,20 @@ from __future__ import annotations
 from collections import deque
 from typing import List, NamedTuple, Sequence, Tuple
 
+import numpy as np
+
 
 class ZRange(NamedTuple):
     lo: int  # inclusive
     hi: int  # inclusive
+
+
+def range_arrays(ranges: Sequence[Tuple[int, int]], dtype=np.int64
+                 ) -> Tuple[np.ndarray, np.ndarray]:
+    """A list of inclusive ranges as two aligned arrays of their lows and
+    highs, the form the z-range covers and key plans carry."""
+    a = np.asarray(ranges, dtype).reshape(-1, 2)
+    return a[:, 0].copy(), a[:, 1].copy()
 
 
 def _merge(ranges: List[ZRange]) -> List[ZRange]:
@@ -48,7 +58,7 @@ def zcover_fast(
     bits: int,
     dims: int,
     max_ranges: int = 2000,
-) -> List[ZRange]:
+) -> Tuple[np.ndarray, np.ndarray]:
     """Cover via the native runtime when built, else the Python BFS below.
 
     Semantics are identical (parity enforced by tests/test_native.py); the
@@ -65,12 +75,12 @@ def zcover(
     bits: int,
     dims: int,
     max_ranges: int = 2000,
-) -> List[ZRange]:
+) -> Tuple[np.ndarray, np.ndarray]:
     """Cover the integer box [lo, hi] (inclusive, per-dim) with z-ranges.
 
     ``lo``/``hi`` are normalized fixed-point coordinates (0 .. 2^bits-1).
-    Returns merged, sorted, inclusive [lo, hi] z-value ranges (ints; values fit
-    in ``dims*bits`` <= 63 bits).
+    Returns merged, sorted, inclusive z-value ranges as two int64 arrays
+    (lows, highs); values fit in ``dims*bits`` <= 63 bits.
     """
     d = dims
     total_bits = d * bits
@@ -130,4 +140,4 @@ def zcover(
                     c_maxs.append(maxs[k] - half)
             frontier.append((zmin + zadd, level + 1, tuple(c_mins), tuple(c_maxs)))
 
-    return _merge(out)
+    return range_arrays(_merge(out))

@@ -24,13 +24,13 @@ the highest bit.
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import List, Sequence, Tuple
+from typing import Tuple
 
 import numpy as np
 
 from geomesa_tpu import config
 from geomesa_tpu.curves.binned_time import BinnedTime, TimePeriod
-from geomesa_tpu.curves.cover import zcover_fast, ZRange
+from geomesa_tpu.curves.cover import zcover_fast
 
 
 # ---------------------------------------------------------------------------
@@ -266,8 +266,9 @@ class Z2SFC:
         xmax: float,
         ymax: float,
         max_ranges: int = None,
-    ) -> List[ZRange]:
-        """Cover the bbox with z-ranges (host-side, plan time)."""
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Cover the bbox with z-ranges (host-side, plan time): inclusive
+        (lows, highs) as two int64 arrays."""
         if max_ranges is None:
             max_ranges = config.SCAN_RANGES_TARGET.to_int()
         lo = (int(self.lon.normalize(xmin)), int(self.lat.normalize(ymin)))
@@ -321,8 +322,9 @@ class Z3SFC:
         ybounds: Tuple[float, float],
         tbounds_ms: Tuple[float, float],
         max_ranges: int = None,
-    ) -> List[ZRange]:
-        """Cover (bbox × time-offset-window) with z-ranges (host, plan time)."""
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Cover (bbox × time-offset-window) with z-ranges (host, plan
+        time): inclusive (lows, highs) as two int64 arrays."""
         if max_ranges is None:
             max_ranges = config.SCAN_RANGES_TARGET.to_int()
         lo = (

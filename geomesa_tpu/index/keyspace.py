@@ -25,7 +25,7 @@ import numpy as np
 
 from geomesa_tpu import config
 from geomesa_tpu.curves.binned_time import BinnedTime, TimePeriod
-from geomesa_tpu.curves.cover import ZRange
+from geomesa_tpu.curves.cover import ZRange, range_arrays
 from geomesa_tpu.curves.xz import XZ2SFC, XZ3SFC
 from geomesa_tpu.curves.zorder import Z2SFC, Z3SFC, split_u64
 from geomesa_tpu.filter import ir
@@ -34,6 +34,10 @@ from geomesa_tpu.schema.columns import ColumnBatch
 from geomesa_tpu.schema.feature_type import FeatureType
 
 MAX_WINDOW_BINS = 64  # collapse per-bin windows beyond this many time bins
+
+
+def _no_ranges() -> np.ndarray:
+    return np.zeros(0, np.int64)
 
 
 @dataclass
@@ -46,12 +50,22 @@ class KeyPlan:
     disjoint: bool = False
     #: full scan (no key constraint)
     full_scan: bool = False
-    #: z-ranges for selectivity estimation (may be empty for full scans)
-    ranges: List[ZRange] = field(default_factory=list)
+    #: cover ranges, inclusive, as two aligned arrays of their low and
+    #: high keys: int64 for the z and xz curves, uint64 for S2 cell ids
+    #: (empty for full scans)
+    lo: np.ndarray = field(default_factory=_no_ranges)
+    hi: np.ndarray = field(default_factory=_no_ranges)
     #: time bins touched (z3/xz3)
     bins: Optional[np.ndarray] = None
     #: estimated fraction of key space covered (coarse; cost input)
     coverage: float = 1.0
+
+    @property
+    def ranges(self) -> List[ZRange]:
+        """The cover as ``ZRange`` objects, derived from ``lo``/``hi`` on
+        each read: for callers off the scan path (explain, histogram
+        estimates, the xz and S2 key spaces' per-range loops)."""
+        return [ZRange(a, b) for a, b in zip(self.lo.tolist(), self.hi.tolist())]
 
     def windows(self, shard_cols: Dict[str, np.ndarray], n: int) -> Tuple[np.ndarray, np.ndarray]:
         """Resolve to (starts, ends) row windows for one shard's host key
@@ -206,27 +220,18 @@ def _merge_cap(los: np.ndarray, his: np.ndarray, cap: int,
     return mlo, mhi
 
 
-def _merge_zranges(ranges: List[Tuple[int, int]], cap: int) -> List[Tuple[int, int]]:
-    """Tuple-list façade over :func:`_merge_cap` (adjacency 1: integer key
-    ranges touching end-to-end fuse)."""
-    if not ranges:
-        return []
-    los = np.asarray([r[0] for r in ranges], np.int64)
-    his = np.asarray([r[1] for r in ranges], np.int64)
-    mlo, mhi = _merge_cap(los, his, cap, adjacent=1)
-    return list(zip(mlo.tolist(), mhi.tolist()))
-
-
-def _per_geom_ranges(cover_fn, bounds_list) -> List[ZRange]:
-    """Cover each query geometry's bounds separately and merge — disjoint
-    bboxes get disjoint covers instead of one envelope cover (reference
+def _merge_covers(covers: Sequence[Tuple[np.ndarray, np.ndarray]]
+                  ) -> Tuple[np.ndarray, np.ndarray]:
+    """Union of several (lows, highs) covers, merged and capped at the
+    range budget (adjacency 1: integer key ranges touching end-to-end
+    fuse). Each query geometry gets its own cover, so disjoint bboxes get
+    disjoint ranges instead of one envelope cover (reference
     FilterHelper.extractGeometries feeds per-geometry ranges the same way)."""
-    all_r: List[Tuple[int, int]] = []
-    for b in bounds_list:
-        for r in cover_fn(b):
-            all_r.append((int(r.lo), int(r.hi)))
-    merged = _merge_zranges(all_r, config.SCAN_RANGES_TARGET.to_int() or 2000)
-    return [ZRange(lo, hi) for lo, hi in merged]
+    return _merge_cap(
+        np.concatenate([lo for lo, _ in covers]),
+        np.concatenate([hi for _, hi in covers]),
+        config.SCAN_RANGES_TARGET.to_int() or 2000, adjacent=1,
+    )
 
 
 def _shift_of(shard_cols: Dict, col: str) -> int:
@@ -249,8 +254,10 @@ def _bin_segments(bins_col: np.ndarray, bins) -> Tuple[np.ndarray, np.ndarray]:
     )
 
 
-def _coverage(ranges: List[ZRange], total_bits: int) -> float:
-    span = sum(r.hi - r.lo + 1 for r in ranges)
+def _coverage(lo: np.ndarray, hi: np.ndarray, total_bits: int) -> float:
+    # in uint64: the whole 63-bit space spans 2^63 keys, one past int64;
+    # merged ranges are disjoint, so the exact sum fits
+    span = int(((hi - lo).astype(np.uint64) + np.uint64(1)).sum(dtype=np.uint64))
     return span / float(1 << total_bits)
 
 
@@ -316,16 +323,14 @@ class Z3KeySpace(KeySpace):
         # disjoint query boxes produce disjoint range sets (Z3Filter.scala
         # checks every window per row — here every window becomes its own
         # scan window at resolve time).
-        ranges = _per_geom_ranges(
-            lambda b: self.sfc.ranges(
-                (b[0], b[2]), (b[1], b[3]), (0.0, max_off)
-            ),
-            xy,
-        )
+        zlo, zhi = _merge_covers([
+            self.sfc.ranges((b[0], b[2]), (b[1], b[3]), (0.0, max_off))
+            for b in xy
+        ])
         # Edge-bin time tightening (Z3IndexKeySpace.getIndexValues:133-158:
         # per-bin offset windows): the first/last bin of each interval gets
         # its own cover restricted to the interval's offsets in that bin.
-        edge: Dict[int, List[Tuple[int, int]]] = {}
+        edge: Dict[int, List[Tuple[np.ndarray, np.ndarray]]] = {}
         for lo, hi in iv:
             blo, olo = self.binned.to_bin_and_offset(np.asarray([lo], np.int64))
             bhi, ohi = self.binned.to_bin_and_offset(np.asarray([hi], np.int64))
@@ -335,21 +340,17 @@ class Z3KeySpace(KeySpace):
                 ((blo, olo, max_off if blo != bhi else ohi),)
                 + (((bhi, 0.0, ohi),) if bhi != blo else ())
             ):
-                rs = [
-                    (int(r.lo), int(r.hi))
-                    for box in xy
-                    for r in self.sfc.ranges(
+                edge.setdefault(b, []).extend(
+                    self.sfc.ranges(
                         (box[0], box[2]), (box[1], box[3]), (off_lo, off_hi)
                     )
-                ]
-                edge.setdefault(b, []).extend(rs)
-        cov = _coverage(ranges, 63) * min(1.0, len(bins) / max(len(bins), 1))
-        plan = KeyPlan(self, ranges=ranges, bins=bins.astype(np.int32), coverage=cov)
+                    for box in xy
+                )
+        cov = _coverage(zlo, zhi, 63) * min(1.0, len(bins) / max(len(bins), 1))
+        plan = KeyPlan(self, lo=zlo, hi=zhi, bins=bins.astype(np.int32),
+                       coverage=cov)
         plan._iv = iv
-        plan._edge = {
-            b: _merge_zranges(rs, config.SCAN_RANGES_TARGET.to_int() or 2000)
-            for b, rs in edge.items()
-        }
+        plan._edge = {b: _merge_covers(cs) for b, cs in edge.items()}
         return plan
 
     def resolve_windows(self, plan, shard_cols, n):
@@ -376,18 +377,14 @@ class Z3KeySpace(KeySpace):
         sets = cache.get((sh, cap))
         if sets is None:
 
-            def needles(rs):
-                merged = _merge_zranges(
-                    [(lo >> sh, hi >> sh) for lo, hi in rs], per_bin_cap
-                )
-                return (
-                    np.asarray([r[0] for r in merged], z_col.dtype),
-                    np.asarray([r[1] for r in merged], z_col.dtype),
-                )
+            def needles(lo, hi):
+                mlo, mhi = _merge_cap(lo >> sh, hi >> sh, per_bin_cap,
+                                      adjacent=1)
+                return mlo.astype(z_col.dtype), mhi.astype(z_col.dtype)
 
             sets = cache[(sh, cap)] = (
-                needles((r.lo, r.hi) for r in plan.ranges),
-                {b: needles(rs) for b, rs in edge.items()},
+                needles(plan.lo, plan.hi),
+                {b: needles(lo, hi) for b, (lo, hi) in edge.items()},
             )
         base, esets = sets
         # Cost per bin, not per range: one lookup finds every bin's segment,
@@ -451,26 +448,22 @@ class Z2KeySpace(KeySpace):
             return KeyPlan(self, disjoint=True)
         if geoms.is_empty:
             return KeyPlan(self, full_scan=True)
-        ranges = _per_geom_ranges(
-            lambda b: self.sfc.ranges(*b),
-            [g.bounds() for g in geoms.values],
+        lo, hi = _merge_covers(
+            [self.sfc.ranges(*g.bounds()) for g in geoms.values]
         )
-        return KeyPlan(self, ranges=ranges, coverage=_coverage(ranges, 62))
+        return KeyPlan(self, lo=lo, hi=hi, coverage=_coverage(lo, hi, 62))
 
     def resolve_windows(self, plan, shard_cols, n):
         # per-range windows (Z2Filter parity): disjoint query boxes scan
         # only their own covers, not the [zmin, zmax] envelope
         z_col = shard_cols["__z2"]
         sh = _shift_of(shard_cols, "__z2")
-        rs = _merge_zranges(
-            [(r.lo >> sh, r.hi >> sh) for r in plan.ranges], shard_window_cap()
-        )
-        if not rs:
+        los, his = _merge_cap(plan.lo >> sh, plan.hi >> sh,
+                              shard_window_cap(), adjacent=1)
+        if not len(los):
             return np.zeros(1, np.int64), np.zeros(1, np.int64)
-        los = np.asarray([r[0] for r in rs], z_col.dtype)
-        his = np.asarray([r[1] for r in rs], z_col.dtype)
-        ws = np.searchsorted(z_col, los, side="left")
-        we = np.searchsorted(z_col, his, side="right")
+        ws = np.searchsorted(z_col, los.astype(z_col.dtype), side="left")
+        we = np.searchsorted(z_col, his.astype(z_col.dtype), side="right")
         keep = we > ws
         if not keep.any():
             return np.zeros(1, np.int64), np.zeros(1, np.int64)
@@ -527,7 +520,8 @@ class XZ2KeySpace(KeySpace):
         ranges = self.sfc.ranges(*bbox)
         total = self.sfc.subtree_size[0]
         span = sum(r.hi - r.lo + 1 for r in ranges)
-        return KeyPlan(self, ranges=ranges, coverage=span / total)
+        lo, hi = range_arrays(ranges)
+        return KeyPlan(self, lo=lo, hi=hi, coverage=span / total)
 
     def resolve_windows(self, plan, shard_cols, n):
         # XZ ranges are NOT contiguous-envelope friendly (singleton parent
@@ -615,7 +609,9 @@ class XZ3KeySpace(KeySpace):
         )
         total = self.sfc.subtree_size[0]
         span = sum(r.hi - r.lo + 1 for r in ranges)
-        return KeyPlan(self, ranges=ranges, bins=bins.astype(np.int32), coverage=span / total)
+        lo, hi = range_arrays(ranges)
+        return KeyPlan(self, lo=lo, hi=hi, bins=bins.astype(np.int32),
+                       coverage=span / total)
 
     def resolve_windows(self, plan, shard_cols, n):
         bins_col = shard_cols["__xz3_bin"]
@@ -627,12 +623,13 @@ class XZ3KeySpace(KeySpace):
             e = np.searchsorted(bins_col, bins[-1], side="right")
             return np.asarray([s], np.int64), np.asarray([e], np.int64)
         starts, ends = [], []
+        ranges = plan.ranges
         seg_lo, seg_hi = _bin_segments(bins_col, bins)
         for s, e in zip(seg_lo.tolist(), seg_hi.tolist()):
             if e <= s:
                 continue
             seg = code_col[s:e]
-            for r in plan.ranges:
+            for r in ranges:
                 s2 = s + np.searchsorted(seg, r.lo >> sh, side="left")
                 e2 = s + np.searchsorted(seg, r.hi >> sh, side="right")
                 if e2 > s2:
@@ -688,7 +685,8 @@ class S2KeySpace(KeySpace):
         bbox = (bs[:, 0].min(), bs[:, 1].min(), bs[:, 2].max(), bs[:, 3].max())
         ranges = self.sfc.ranges(*bbox)
         span = sum(r.hi - r.lo + 1 for r in ranges)
-        return KeyPlan(self, ranges=ranges, coverage=span / float(6 << 60))
+        lo, hi = range_arrays(ranges, np.uint64)  # cell ids use all 64 bits
+        return KeyPlan(self, lo=lo, hi=hi, coverage=span / float(6 << 60))
 
     def resolve_windows(self, plan, shard_cols, n):
         col = shard_cols["__s2"]
@@ -766,30 +764,33 @@ class S3KeySpace(KeySpace):
             np.concatenate([self.binned.bins_between(lo, hi) for lo, hi in iv])
         )
         if geoms.is_empty:
-            return KeyPlan(self, ranges=[], bins=bins.astype(np.int32), coverage=1.0)
+            return KeyPlan(self, bins=bins.astype(np.int32), coverage=1.0)
         bs = np.asarray([g.bounds() for g in geoms.values])
         bbox = (bs[:, 0].min(), bs[:, 1].min(), bs[:, 2].max(), bs[:, 3].max())
         ranges = self.sfc.ranges(*bbox)
         span = sum(r.hi - r.lo + 1 for r in ranges)
         cov = span / float(6 << 60)
-        return KeyPlan(self, ranges=ranges, bins=bins.astype(np.int32), coverage=cov)
+        lo, hi = range_arrays(ranges, np.uint64)
+        return KeyPlan(self, lo=lo, hi=hi, bins=bins.astype(np.int32),
+                       coverage=cov)
 
     def resolve_windows(self, plan, shard_cols, n):
         bins_col = shard_cols["__s3_bin"]
         col = shard_cols["__s3"]
         sh = _shift_of(shard_cols, "__s3")
         bins = plan.bins
-        if len(bins) > 8 or not plan.ranges:
+        if len(bins) > 8 or not len(plan.lo):
             s = np.searchsorted(bins_col, bins[0], side="left")
             e = np.searchsorted(bins_col, bins[-1], side="right")
             return np.asarray([s], np.int64), np.asarray([e], np.int64)
         starts, ends = [], []
+        ranges = plan.ranges
         seg_lo, seg_hi = _bin_segments(bins_col, bins)
         for s, e in zip(seg_lo.tolist(), seg_hi.tolist()):
             if e <= s:
                 continue
             seg = col[s:e]
-            for r in plan.ranges:
+            for r in ranges:
                 s2_ = s + np.searchsorted(seg, np.uint64(r.lo >> sh), side="left")
                 e2_ = s + np.searchsorted(seg, np.uint64(r.hi >> sh), side="right")
                 if e2_ > s2_:

@@ -202,9 +202,10 @@ def deinterleave3(z: np.ndarray) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
 def zcover(
     lo: Sequence[int], hi: Sequence[int], bits: int, dims: int,
     max_ranges: int = 2000,
-):
-    """Native z-range cover; returns List[ZRange]. Falls back to Python."""
-    from geomesa_tpu.curves.cover import ZRange, zcover as py_zcover
+) -> Tuple[np.ndarray, np.ndarray]:
+    """Native z-range cover: merged, sorted, inclusive ranges as two int64
+    arrays (lows, highs). Falls back to Python."""
+    from geomesa_tpu.curves.cover import zcover as py_zcover
 
     L = lib()
     if L is None:
@@ -219,7 +220,8 @@ def zcover(
         # invalid args (-2: Python raises the descriptive error) or
         # capacity overflow (-1): resolve through the fallback either way
         return py_zcover(lo, hi, bits, dims, max_ranges)
-    return [ZRange(int(out_lo[i]), int(out_hi[i])) for i in range(n)]
+    # z-values use at most 63 bits (bits * dims <= 63): the cast is exact
+    return out_lo[:n].astype(np.int64), out_hi[:n].astype(np.int64)
 
 
 def java_hash(values: Sequence[str]) -> np.ndarray:

@@ -690,12 +690,14 @@ class Executor:
             with tracing.span("scan.windows.fine") as sp, \
                     config.SCAN_RANGES_TARGET.scoped(cover), \
                     ksmod.window_cap(cover):
-                fine_kp = table.keyspace.plan(self.store.ft, plan.filter)
+                with tracing.span("scan.cover") as cover_sp:
+                    fine_kp = table.keyspace.plan(self.store.ft, plan.filter)
+                    if fine_kp is not None:
+                        cover_sp.set(ranges=len(fine_kp.lo))
                 if fine_kp is not None:
                     out = table.windows(fine_kp)
                     if sp is not tracing.NOOP:
-                        sp.set(rows=_admitted(*out),
-                               ranges=len(fine_kp.ranges))
+                        sp.set(rows=_admitted(*out), ranges=len(fine_kp.lo))
         except Exception:
             logging.getLogger(__name__).warning(
                 "fine window resolution failed; using the planner windows",

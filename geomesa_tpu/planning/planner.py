@@ -121,7 +121,7 @@ class QueryPlanner:
         exp.pop()
         exp.line(
             f"Chosen index: {chosen.keyspace.name} "
-            f"(estimated count {cost:.0f}, {len(chosen.ranges)} ranges"
+            f"(estimated count {cost:.0f}, {len(chosen.lo)} ranges"
             + (f", {len(chosen.bins)} time bins" if chosen.bins is not None else "")
             + ")"
         )

@@ -50,11 +50,9 @@ def _bound_points(ft: FeatureType):
         for target in (2000, RAISED):
             with config.SCAN_RANGES_TARGET.scoped(target):
                 kp = ksp.plan(ft, parse_ecql(ecql))
-            sets = [(plain, [(r.lo, r.hi) for r in kp.ranges])]
-            for b, rs in list(sets) + list(kp._edge.items()):
-                for r in rs:
-                    zs += r
-                    bins += [b, b]
+            for b, (lo, hi) in [(plain, (kp.lo, kp.hi))] + list(kp._edge.items()):
+                zs += lo.tolist() + hi.tolist()
+                bins += [b] * (2 * len(lo))
     z = np.asarray(zs, np.uint64)
     x, y, off = ksp.sfc.invert(z)
     off = np.rint(off).astype(np.int64)
@@ -99,12 +97,12 @@ def _oracle_shard(plan, bins_col, z_col, sh, cap):
     """Per-range, per-bin windows of one shard (the replaced algorithm)."""
     per_bin_cap = max(1, cap // max(len(plan.bins), 1))
 
-    def shifted(rs):
-        return ks._merge_zranges([(lo >> sh, hi >> sh) for lo, hi in rs],
-                                 per_bin_cap)
+    def shifted(lo, hi):
+        mlo, mhi = ks._merge_cap(lo >> sh, hi >> sh, per_bin_cap, adjacent=1)
+        return list(zip(mlo.tolist(), mhi.tolist()))
 
-    base = shifted((r.lo, r.hi) for r in plan.ranges)
-    edge = {b: shifted(rs) for b, rs in plan._edge.items()}
+    base = shifted(plan.lo, plan.hi)
+    edge = {b: shifted(lo, hi) for b, (lo, hi) in plan._edge.items()}
     work = [(b, base) for b in plan.bins.tolist() if b not in edge]
     work += list(edge.items())
     starts, ends = [], []
@@ -193,7 +191,7 @@ def test_z3_resolve_windows_searches_per_bin_not_per_range(monkeypatch):
     ksp = table.keyspace
     with config.SCAN_RANGES_TARGET.scoped(RAISED), ks.window_cap(RAISED):
         plan = _plan(fs, QUERIES["two_boxes"])
-        assert len(plan.ranges) >= 2000
+        assert len(plan.lo) >= 2000
 
         def refuse(*a, **k):
             raise AssertionError("per-range native search")

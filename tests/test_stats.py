@@ -140,7 +140,8 @@ def test_z3histogram_estimate(cols):
     est = z.estimate_count(bins, whole)
     assert est == pytest.approx(5000, rel=0.01)
     # A small-bbox cover must be monotonically smaller, never negative.
-    ranges = sfc.ranges((-75, -73), (40, 42), (0, float(sfc.binned.max_offset_ms)))
+    lo, hi = sfc.ranges((-75, -73), (40, 42), (0, float(sfc.binned.max_offset_ms)))
+    ranges = [ZRange(a, b) for a, b in zip(lo.tolist(), hi.tolist())]
     sub = z.estimate_count(bins, ranges)
     assert 0 <= sub <= est
     rt = roundtrip(z)

@@ -471,8 +471,8 @@ def test_cli_trace_and_metrics(tmp_path, capsys):
 
 PHASE_Q = ("BBOX(geom, -100, 30, -90, 35) AND dtg DURING "
            "2020-01-05T00:00:00Z/2020-01-20T00:00:00Z")
-MISS_SPANS = ("scan.windows", "scan.windows.fine", "scan.compact",
-              "scan.schedule", "scan.gather")
+MISS_SPANS = ("scan.windows", "scan.windows.fine", "scan.cover",
+              "scan.compact", "scan.schedule", "scan.gather")
 
 
 def _spans(tree, acc=None):
@@ -509,8 +509,11 @@ def test_cold_compact_density_has_phase_spans(traced, compact_on):
         assert name in spans, (name, sorted(spans))
     win = spans["scan.windows"][0]["attrs"]
     assert win["rows"] > 0
-    fine = spans["scan.windows.fine"][0]["attrs"]
-    assert fine["rows"] > 0 and fine["ranges"] > 0
+    fine = spans["scan.windows.fine"][0]
+    assert fine["attrs"]["rows"] > 0 and fine["attrs"]["ranges"] > 0
+    # the re-cover is split from the window resolution
+    assert [c["name"] for c in fine.get("children", ())] == ["scan.cover"]
+    assert fine["children"][0]["attrs"] == {"ranges": fine["attrs"]["ranges"]}
     # the fine cover runs inside the compaction that chose between the sets
     compact = spans["scan.compact"][0]
     assert [c["name"] for c in compact.get("children", ())] == \
