@@ -247,14 +247,6 @@ COMPACT_MIN_ROWS = SystemProperty("geomesa.compact.min.rows", str(1 << 20))
 #: table (windows admitting most rows can't win).
 COMPACT_FRACTION = SystemProperty("geomesa.compact.fraction", "0.5")
 
-#: Chunk slab length override (0 = adaptive: least padding, largest B
-#: within 10%).
-COMPACT_B = SystemProperty("geomesa.compact.b", "0")
-
-#: Range-cover budget for the compact path's fine (gap-union-free) window
-#: resolution; <= geomesa.scan.ranges.target disables the fine pass.
-COMPACT_COVER = SystemProperty("geomesa.compact.cover", "32768")
-
 #: Bucket compiled-kernel shapes (padded window count K to a power of two
 #: above the floor below; compact chunk counts already follow the
 #: geometric ladder in kernels/density_mxu.ladder8) so distinct-but-similar
@@ -276,8 +268,8 @@ COMPACT_SHARD_BUCKET = SystemProperty("geomesa.compact.shard.bucket", "8192")
 #: Capacity of the shared compiled-kernel LRU registry (entries). Evicts
 #: least-recently-used kernels one at a time — never clear-on-overflow.
 #: Raised 256 -> 512 with the query-axis batch kernels (their padded
-#: member axis multiplies the key space ~5x for batch sites; BENCH_r10
-#: measured 615 recompiles / 359 evictions across the full bench at 256
+#: member axis multiplies the key space ~5x for batch sites; the
+#: recompile and eviction counts behind it were not measured on the chip
 #: — docs/PERF.md "Registry pressure").
 KERNEL_CACHE_SIZE = SystemProperty("geomesa.kernel.cache.size", "512")
 
@@ -291,26 +283,6 @@ COMPILE_CACHE_DIR = SystemProperty("geomesa.compile.cache.dir", None)
 #: execution (one prefetch thread, one in-flight partition; compile and
 #: dispatch stay on the query thread).
 PIPELINE_PREFETCH = SystemProperty("geomesa.pipeline.prefetch", "true")
-
-#: Use the scatter-free MXU density kernel on z-indexed tables.
-DENSITY_MXU = SystemProperty("geomesa.density.mxu", "true")
-
-#: Use the Pallas grouped one-hot-matmul density kernel (preferred over
-#: the XLA einsum pair kernel when the backend supports pallas; measured
-#: ~5x over scatter and ~6x over the einsum at the bench shape).
-DENSITY_PALLAS = SystemProperty("geomesa.density.pallas", "true")
-
-#: Pallas density bails out (to the einsum/scatter fallbacks) when the
-#: pair expansion would duplicate rows beyond this factor.
-DENSITY_PALLAS_MAX_DUP = SystemProperty("geomesa.density.pallas.max.dup", "4.0")
-
-#: Split the padded-path density scatter into this many independent
-#: pieces (measured ~10x on v5e); <=1 disables.
-SCATTER_SPLIT = SystemProperty("geomesa.scatter.split", "8")
-
-#: MXU density grid tile shape (cells).
-MXU_TILE_X = SystemProperty("geomesa.mxu.tile.x", "64")
-MXU_TILE_Y = SystemProperty("geomesa.mxu.tile.y", "32")
 
 #: Bin-space (2-D mesh) streaming: lax.scan chunk count per device along
 #: the time-bin axis (1 = no streaming; >1 trades HBM for steps).

@@ -11,6 +11,9 @@ from __future__ import annotations
 
 import numpy as np
 
+#: independent scatter pieces of a device density grid
+SCATTER_SPLIT = 8
+
 
 def density_grid(x, y, mask, bbox, width: int, height: int, weight=None, xp=None):
     """Masked 2D histogram: points -> (height, width) float32 grid.
@@ -62,20 +65,14 @@ def density_grid_at(x, y, mask, x0, y0, dx, dy, width: int, height: int,
         np.add.at(grid, flat_idx, w)
         return grid.reshape(height, width)
     # Split the scatter into independent pieces accumulating separate
-    # grids. Measured on v5e with pre-staged inputs, 8 independent pow2
-    # scatters + grid adds ran ~10x faster than one scatter; re-measured
-    # r4 FUSED behind a mask compute in one jit, the split shows no gain
-    # (~7 ns/update either way — XLA serializes the pieces after the
-    # shared producer). Kept because it never hurts and the pre-staged
-    # shape still benefits; the real fix is the pallas kernel
-    # (density_pallas.py), which replaces this path on z-indexed tables.
-    # Pieces must divide evenly — callers keep row counts a multiple of 8
-    # (see executor chunk buckets).
-    from geomesa_tpu import config
-
-    P = config.SCATTER_SPLIT.to_int() or 0
+    # grids, for XLA to schedule apart (not measured on the chip: fused
+    # behind a mask compute in one jit, XLA may serialize the pieces after
+    # the shared producer). The pallas kernel (density_pallas.py) replaces
+    # this path on z-indexed compact scans. Pieces must divide evenly —
+    # callers keep row counts a multiple of 8 (see executor chunk buckets).
+    P = SCATTER_SPLIT
     n = flat_idx.shape[0]
-    if P <= 1 or n % P or n < (1 << 14):
+    if n % P or n < (1 << 14):
         return (
             xp.zeros(height * width, xp.float32).at[flat_idx].add(w)
         ).reshape(height, width)

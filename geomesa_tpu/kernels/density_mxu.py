@@ -23,15 +23,10 @@ from typing import Dict, Optional
 
 import numpy as np
 
-def tile_shape():
-    """Grid tile shape (TY, TX) in cells: the measured optimum on v5e for
-    fine-cover chunk boxes (~30-70 cells) — smaller tiles raise
-    pairs-per-chunk, larger tiles raise one-hot operand and tile-tensor
-    traffic. Tunable via geomesa.mxu.tile.y/x."""
-    from geomesa_tpu import config
-
-    return (config.MXU_TILE_Y.to_int() or 32,
-            config.MXU_TILE_X.to_int() or 64)
+#: grid tile shape (TY, TX) in cells, sized for fine-cover chunk boxes
+#: (~30-70 cells): smaller tiles raise pairs-per-chunk, larger tiles raise
+#: one-hot operand and tile-tensor traffic (not measured on the chip)
+TILE_SHAPE = (32, 64)
 
 
 #: pair-batch row budget: PB pairs x B rows ~ 512Ki rows per matmul batch
@@ -53,20 +48,20 @@ def ladder8(n: int) -> int:
 
 
 def _chunk_boxes(compact: Dict, table, col: str, dims: int, shift: int,
-                 box_cache: Optional[Dict], version):
+                 box_cache, version):
     """Exact per-chunk normalized-index boxes from the sorted key column:
     deinterleave every window row's (quantized) key, segment-min/max per
     chunk via ``reduceat``. Exact up to key quantization (each quantized
     cell contributes its full extent), which end-point prefix cubes are
     not: a scan window is a gap-union of cover ranges, so a chunk's
     end-point cube can span the whole union while its rows sit in two
-    small clusters. Cached per (windows, store version) — ~ms for millions
-    of rows, amortized across grids and repeat queries."""
+    small clusters. Cached in ``box_cache`` (a ``viewcache.BoundedCache``)
+    per (windows, store version) — ~ms for millions of rows, amortized
+    across grids and repeat queries."""
     ckey = (compact["whash"], compact["B"], col, table.n, version)
-    if box_cache is not None:
-        hit = box_cache.get(ckey)
-        if hit is not None:
-            return hit
+    hit = box_cache.get(ckey)
+    if hit is not None:
+        return hit
     from geomesa_tpu.curves.zorder import deinterleave2, deinterleave3
 
     key = table.key_columns[col]
@@ -98,16 +93,12 @@ def _chunk_boxes(compact: Dict, table, col: str, dims: int, shift: int,
         full_lo[act] = lo_d
         full_hi[act] = hi_d
         out.append((full_lo, full_hi))
-    if box_cache is not None:
-        if len(box_cache) >= 64:
-            box_cache.clear()
-        box_cache[ckey] = out
-    return out
+    return box_cache.put(ckey, out)
 
 
 def pair_candidates(
     compact: Dict, table, keyspace, bbox, width: int, height: int,
-    TY: int, TX: int, box_cache: Optional[Dict] = None, version=None,
+    TY: int, TX: int, box_cache, version,
 ) -> Optional[Dict]:
     """Host-side (chunk, tile) candidate list for the compacted scan layout.
 
@@ -193,10 +184,10 @@ def pair_candidates(
 
 def build_pairs(
     compact: Dict, table, keyspace, bbox, width: int, height: int,
-    box_cache: Optional[Dict] = None, version=None,
+    box_cache, version,
 ) -> Optional[Dict]:
     """(chunk, tile) pair arrays shaped for the XLA einsum kernel."""
-    TY, TX = tile_shape()
+    TY, TX = TILE_SHAPE
     cand = pair_candidates(
         compact, table, keyspace, bbox, width, height, TY, TX,
         box_cache, version,

@@ -65,20 +65,16 @@ SEGMENT = 16384
 _OFFGRID = np.int32(1 << 20)
 
 
-def max_dup() -> float:
-    """The pair budget: the grouped kernel serves a view only when its
-    (chunk, tile) pairs are at most this many times its real chunks."""
-    from geomesa_tpu import config
-
-    md = config.DENSITY_PALLAS_MAX_DUP.to_float()
-    return 4.0 if md is None else md
+#: the pair budget: the grouped kernel serves a view only when its
+#: (chunk, tile) pairs are at most this many times its real chunks
+MAX_DUP = 4.0
 
 
 def build_grouped(cand: Dict, B: int) -> Dict:
     """Host-side pair schedule for the grouped kernel from the view's
     ``TILE``-square candidates (``pair_candidates``): (superchunk, tile)
     pairs sorted by tile id, one pallas grid step per pair. The caller
-    has checked the pair budget (:func:`max_dup`)."""
+    has checked the pair budget (:data:`MAX_DUP`)."""
     ntx, nty, P = cand["ntx"], cand["nty"], cand["P"]
     ntiles = ntx * nty
     chunk_of, tx, ty = cand["chunk_of"], cand["tx"], cand["ty"]

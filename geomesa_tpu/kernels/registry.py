@@ -12,7 +12,8 @@ is the executor's warm-path substrate (docs/PERF.md):
   63 hot kernels to admit the 65th. Default capacity 512
   (``geomesa.kernel.cache.size``; raised from 256 when the query-axis
   batch kernels widened the key space with the padded member axis —
-  docs/PERF.md records the BENCH_r10 eviction pressure behind the raise).
+  docs/PERF.md "Registry pressure"; the eviction counts behind the raise
+  were not measured on the chip).
 * **version-stable keys** — kernel cache keys carry NO store version: the
   compiled function is structure-only (shapes + predicate closure), so a
   store mutation must not recompile anything. What CAN invalidate a

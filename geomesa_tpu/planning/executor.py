@@ -28,6 +28,7 @@ from geomesa_tpu.kernels.registry import (
     KernelRegistry, dict_fingerprint, enable_persistent_cache,
 )
 from geomesa_tpu.planning.planner import QueryPlan
+from geomesa_tpu.planning.viewcache import caches_of
 from geomesa_tpu.schema.columns import ColumnBatch
 from geomesa_tpu.stats import sketches as sk
 
@@ -52,6 +53,11 @@ from geomesa_tpu.resilience import (  # noqa: E402  (re-export)
 # not rows *stored* (the same property the reference gets from range scans:
 # AbstractBatchScan.scala:32 only ever reads the planned ranges).
 _SLAB_GATHER_FNS: Dict[int, Any] = {}
+
+#: Range-cover budget of the compact path's fine (gap-union-free) window
+#: resolution (:meth:`Executor._fine_windows`); a planner budget
+#: (geomesa.scan.ranges.target) at or above it turns the fine pass off.
+FINE_COVER = 32768
 
 
 def _slab_gather_fn(B: int):
@@ -188,9 +194,7 @@ class Executor:
                 starts, ends = table.windows(plan.key_plan)
                 scanned = _admitted(starts, ends)
                 sp.set(rows=scanned)
-            if len(cache) >= 64:
-                cache.clear()
-            cache[rkey] = (starts, ends)
+            cache.put(rkey, (starts, ends))
         counts = np.diff(table.shard_bounds).astype(np.int32)
         L = table.shard_len
         needed = list(dict.fromkeys(list(plan.compiled.columns) + list(extra_cols)))
@@ -224,16 +228,13 @@ class Executor:
                 # per-code kernel on offset values (preserving the
                 # reference's exact per-key counters); wide key spaces
                 # hash-bucket. min/max cached per store version.
-                span_cache = self.store.__dict__.setdefault("_sb_span", {})
+                span_cache = caches_of(self.store).sample_spans
                 skey = (sb, plan.index_name, self.version_source.version)
                 rng = span_cache.get(skey)
                 if rng is None:
                     col = table.col_sorted(sb)
-                    rng = ((int(col.min()), int(col.max()))
-                           if len(col) else (0, -1))
-                    if len(span_cache) >= 64:
-                        span_cache.clear()
-                    span_cache[skey] = rng
+                    rng = span_cache.put(skey, (int(col.min()), int(col.max()))
+                                         if len(col) else (0, -1))
                 lo_v, hi_v = rng
                 if 0 <= hi_v - lo_v < 256:
                     sb_mode, sb_off = "exact-span", lo_v
@@ -306,16 +307,8 @@ class Executor:
             rows_at = {
                 Bc: int((-(-flat // Bc)).sum()) * Bc for Bc in ladder
             }
-            override = config.COMPACT_B.to_int() or 0
-            if override:
-                # clamp the knob into the legal ladder (values off the
-                # ladder or > L would break the slab clamp arithmetic)
-                B = min(ladder, key=lambda b: abs(b - override))
-            else:
-                floor_rows = min(rows_at.values())
-                B = max(
-                    b for b, r in rows_at.items() if r <= 1.10 * floor_rows
-                )
+            floor_rows = min(rows_at.values())
+            B = max(b for b, r in rows_at.items() if r <= 1.10 * floor_rows)
             return B, rows_at[B], lens
 
         cands = []
@@ -366,8 +359,7 @@ class Executor:
         # rebuild (it dwarfs the per-call jit dispatch on cached plans)
         ckey = ("compact_desc", self.store.uid, self.store.version,
                 plan.index_name, plan.__dict__.get("window_token"),
-                config.COMPACT_B.to_int(), config.COMPACT_FRACTION.to_float(),
-                config.COMPACT_COVER.to_int())
+                config.COMPACT_FRACTION.to_float())
         ccache, ckey = self._resolve_cache(plan, ckey)
         chit = ccache.get(ckey)
         if chit is not None:
@@ -383,9 +375,7 @@ class Executor:
         L = setup["L"]
         chosen = self._compact_candidates(plan, setup)
         if chosen is None:
-            if len(ccache) >= 64:
-                ccache.clear()
-            ccache[ckey] = False
+            ccache.put(ckey, False)
             return
         starts, ends, B, lens = chosen
         S, K = starts.shape
@@ -401,25 +391,19 @@ class Executor:
         # by the bytes, never their hash — a collision would silently
         # scan another query's rows, and equality is the correctness
         # contract (the arrays are small next to the slabs they index).
-        share = self.store.__dict__.setdefault("_desc_share", {})
+        share = caches_of(self.store).shared_descriptors
         skey = ("flat", B, S, L, starts.tobytes(), ends.tobytes())
         shit = share.get(skey)
         if shit is not None:
             metrics.inc(metrics.COMPACT_DESC_SHARED)
-            if len(ccache) >= 64:
-                ccache.clear()
-            ccache[ckey] = shit
+            ccache.put(ckey, shit)
             setup["compact"] = shit or None
             return
         frac = config.COMPACT_FRACTION.to_float()
         if C * B >= table.n * (0.5 if frac is None else frac):
             # windows admit most of the table: compaction can't win
-            if len(ccache) >= 64:
-                ccache.clear()
-            ccache[ckey] = False
-            if len(share) >= 64:
-                share.clear()
-            share[skey] = False
+            ccache.put(ckey, False)
+            share.put(skey, False)
             return
         win = np.repeat(np.arange(S * K), nc)
         j = np.arange(C) - np.repeat(np.cumsum(nc) - nc, nc)
@@ -451,12 +435,8 @@ class Executor:
             "valid": valid,
             "whash": hash((starts.tobytes(), ends.tobytes())),
         }
-        if len(ccache) >= 64:
-            ccache.clear()
-        ccache[ckey] = desc
-        if len(share) >= 64:
-            share.clear()
-        share[skey] = desc
+        ccache.put(ckey, desc)
+        share.put(skey, desc)
         setup["compact"] = desc
 
     # -- mesh-sharded window compaction -----------------------------------
@@ -481,8 +461,7 @@ class Executor:
         for these windows (cached)."""
         ckey = ("compact_mesh", self.store.uid, self.store.version,
                 plan.index_name, plan.__dict__.get("window_token"), D,
-                config.COMPACT_B.to_int(), config.COMPACT_FRACTION.to_float(),
-                config.COMPACT_COVER.to_int())
+                config.COMPACT_FRACTION.to_float())
         cache, ckey = self._resolve_cache(plan, ckey)
         hit = cache.get(ckey)
         if hit is not None:
@@ -497,7 +476,7 @@ class Executor:
         L = setup["L"]
         chosen = self._compact_candidates(plan, setup)
         out = False
-        share = self.store.__dict__.setdefault("_desc_share", {})
+        share = caches_of(self.store).shared_descriptors
         skey = None
         if chosen is not None:
             starts, ends, B, lens = chosen
@@ -511,9 +490,7 @@ class Executor:
             shit = share.get(skey)
             if shit is not None:
                 metrics.inc(metrics.COMPACT_DESC_SHARED)
-                if len(cache) >= 64:
-                    cache.clear()
-                cache[ckey] = shit
+                cache.put(ckey, shit)
                 return shit or None
             Sd = S // D
             flat_lens = lens.reshape(-1)
@@ -553,13 +530,9 @@ class Executor:
                     "cstart": a_cstart, "lo": a_lo, "valid": a_valid,
                     "whash": hash((starts.tobytes(), ends.tobytes())),
                 }
-        if len(cache) >= 64:
-            cache.clear()
-        cache[ckey] = out
+        cache.put(ckey, out)
         if skey is not None:
-            if len(share) >= 64:
-                share.clear()
-            share[skey] = out
+            share.put(skey, out)
         return out or None
 
     def _compact_mesh_run(self, plan: QueryPlan, setup, agg_fn, agg_cols,
@@ -630,18 +603,15 @@ class Executor:
             site = str(cache_key[0]) if cache_key else "agg"
             go = jax.jit(_named(sm, f"compact_mesh_{site}"))
             fn_cache.put(fn_key, go)
-        wcache = self.store.__dict__.setdefault("_win_cache", {})
+        wcache = caches_of(self.store).device_windows
         wkey = ("mesh_win", d["whash"], B, Cp, D, self.store.uid,
                 self.store.version)
         win = wcache.get(wkey)
         if win is None:
             sh = self._sharding()
-            win = tuple(
+            win = wcache.put(wkey, tuple(
                 jax.device_put(d[k], sh) for k in ("cstart", "lo", "valid")
-            )
-            if len(wcache) >= 64:
-                wcache.clear()
-            wcache[wkey] = win
+            ))
         with self._kernel_span(str(cache_key[0]) if cache_key else None,
                                setup, compact=True, rows=D * Cp * B):
             return go(
@@ -654,11 +624,8 @@ class Executor:
         fresh plan of the same query hits), else the plan itself."""
         token = plan.__dict__.get("cache_token")
         if token is not None:
-            return (
-                self.store.__dict__.setdefault("_win_resolve_cache", {}),
-                key + (token,),
-            )
-        return plan.__dict__.setdefault("_win_resolve_cache", {}), key
+            return caches_of(self.store).resolved, key + (token,)
+        return caches_of(plan).resolved, key
 
     def _fine_windows(self, plan: QueryPlan, setup):
         """Scan windows re-resolved from a RE-COVERED key plan under a much
@@ -670,13 +637,13 @@ class Executor:
         row and the MXU density kernel wants spatially TIGHT chunks, so a
         16-64x finer cover pays for itself immediately. Cover + resolve
         run once per (plan, store version) and are cached on the plan.
-        (None, None) when disabled or the keyspace can't re-plan."""
-        cover = config.COMPACT_COVER.to_int() or 0
+        (None, None) when the planner's own budget is already at least
+        :data:`FINE_COVER` or the keyspace can't re-plan."""
         from geomesa_tpu.index import keyspace as ksmod
 
-        if cover <= (config.SCAN_RANGES_TARGET.to_int() or 2000):
+        if FINE_COVER <= (config.SCAN_RANGES_TARGET.to_int() or 2000):
             return None, None
-        rkey = ("fine", cover, self.store.uid, self.store.version,
+        rkey = ("fine", self.store.uid, self.store.version,
                 plan.index_name, plan.__dict__.get("window_token"),
                 config.COMPACT_BUCKETING.to_bool(),
                 config.COMPACT_BUCKET_FLOOR.to_int())
@@ -688,8 +655,8 @@ class Executor:
         try:
             table = setup["table"]
             with tracing.span("scan.windows.fine") as sp, \
-                    config.SCAN_RANGES_TARGET.scoped(cover), \
-                    ksmod.window_cap(cover):
+                    config.SCAN_RANGES_TARGET.scoped(FINE_COVER), \
+                    ksmod.window_cap(FINE_COVER):
                 with tracing.span("scan.cover") as cover_sp:
                     fine_kp = table.keyspace.plan(self.store.ft, plan.filter)
                     if fine_kp is not None:
@@ -703,10 +670,7 @@ class Executor:
                 "fine window resolution failed; using the planner windows",
                 exc_info=True,
             )
-        if len(cache) >= 64:
-            cache.clear()
-        cache[rkey] = out
-        return out
+        return cache.put(rkey, out)
 
     def _compact_cols(self, setup, names):
         """Window rows of ``names`` as device [C, B] slabs, gathered from
@@ -714,7 +678,7 @@ class Executor:
         version, device pin)."""
         d = setup["compact"]
         B, Cp = d["B"], d["C"]
-        cache = self.store.__dict__.setdefault("_compact_cache", {})
+        cache = caches_of(self.store).slabs
         key0 = (d["whash"], self.store.uid, self.store.version, B, Cp,
                 self._devkey())
         out, missing = {}, []
@@ -728,15 +692,12 @@ class Executor:
                 )
                 g = self._put(d["cstart"])
                 gather = _slab_gather_fn(B)
-                if len(cache) >= 64:
-                    cache.clear()
                 with tracing.span("scan.gather", columns=len(missing),
                                   rows=Cp * B):
                     utilization.dispatched(self._devkey() or 0)
                     for n in missing:
-                        out[n] = cache[key0 + (n,)] = gather(
-                            full[n].reshape(-1), g
-                        )
+                        out[n] = gather(full[n].reshape(-1), g)
+                cache.put_all({key0 + (n,): out[n] for n in missing})
         return out
 
     def _device_compact_agg(self, plan: QueryPlan, setup, agg_fn, agg_cols=(),
@@ -809,15 +770,12 @@ class Executor:
                 self._note(plan, kernel="trace")
         elif fn_cache is not None:
             self._note(plan, kernel="hit")
-        wcache = self.store.__dict__.setdefault("_win_cache", {})
+        wcache = caches_of(self.store).device_windows
         wkey = ("compact_win", d["whash"], B, Cp, self.store.uid,
                 self.store.version, self._devkey())
         win = wcache.get(wkey)
         if win is None:
-            win = (self._put(d["lo"]), self._put(d["valid"]))
-            if len(wcache) >= 64:
-                wcache.clear()
-            wcache[wkey] = win
+            win = wcache.put(wkey, (self._put(d["lo"]), self._put(d["valid"])))
         with self._kernel_span(site, setup, compact=True, rows=Cp * B):
             return go(cols, win[0], win[1], tuple(extra))
 
@@ -888,11 +846,9 @@ class Executor:
         if compiled.band is None:
             return None
         token = plan.__dict__.get("cache_token")
-        vc = (
-            self.version_source.__dict__.setdefault("_band_verdicts", {})
-            if token is not None
-            else plan.__dict__.setdefault("_band_verdicts", {})
-        )
+        vc = caches_of(
+            self.version_source if token is not None else plan
+        ).band_verdicts
         # the verdict depends on the SCAN WINDOWS too (kNN reuses one token
         # across expanding boxes): fingerprint them into the key
         vkey = (
@@ -932,11 +888,8 @@ class Executor:
             if keep.ndim == 0:
                 keep = np.full(len(idx), bool(keep))
             idx = idx[keep.astype(bool)]
-        info = idx.astype(np.int64)  # sorted-order row positions, maybe empty
-        if len(vc) >= 256:
-            vc.clear()
-        vc[vkey] = info
-        return info
+        # sorted-order row positions, maybe empty
+        return vc.put(vkey, idx.astype(np.int64))
 
     def _band_correction(self, plan: QueryPlan, setup, info, agg_fn_host,
                          agg_cols, extra):
@@ -1167,10 +1120,9 @@ class Executor:
             # their scan windows (knn radius expansion) key window arrays
             # separately without forcing a retrace
             wtoken = plan.__dict__.get("window_token", token)
-            if token is not None:
-                wcache = self.store.__dict__.setdefault("_win_cache", {})
-            else:
-                wcache = plan.__dict__.setdefault("_win_cache", {})
+            wcache = caches_of(
+                self.store if token is not None else plan
+            ).device_windows
             wkey = (fn_key, wtoken, self.store.uid, self.store.version,
                     self._devkey())
             win = wcache.get(wkey)
@@ -1181,9 +1133,7 @@ class Executor:
                 self._put(setup["counts"]),
             )
             if fn_key is not None:
-                if len(wcache) >= 64:
-                    wcache.clear()
-                wcache[wkey] = win
+                wcache.put(wkey, win)
         d_starts, d_ends, d_counts = win
         from geomesa_tpu.kernels import pallas_kernels as pk
 
@@ -1307,31 +1257,29 @@ class Executor:
 
     def _density_ladder(self, setup, bbox, width, height):
         """The density kernel ladder's choice for one view of the compact
-        layout: ``(kernel, schedule, P, C)``. ``grouped`` (the pallas
-        kernel) when pallas runs here and the view's ``TILE``-square
-        (chunk, tile) candidates P are within ``max_dup`` times its real
-        chunks C; else ``mxu`` (the XLA einsum pair kernel); else
-        ``scatter``, with no schedule (also when the index has no morton
-        key). Built on the host and its arrays placed on the device once
-        per (windows, grid, store version, device pin, ladder options), in
-        a ``scan.schedule`` span; a cache hit returns the same P and C."""
+        layout: ``(kernel, schedule, P, C)``, from what it observes.
+        ``grouped`` (the pallas kernel) when pallas runs here and the
+        view's ``TILE``-square (chunk, tile) candidates P are within
+        ``MAX_DUP`` times its real chunks C; else ``mxu`` (the XLA einsum
+        pair kernel); else ``scatter``, with no schedule (when the index
+        has no morton key). Built on the host and its arrays placed on the
+        device once per (windows, grid, store version, device pin), in a
+        ``scan.schedule`` span; a cache hit returns the same P and C."""
         from geomesa_tpu.kernels import density_mxu as _dm
         from geomesa_tpu.kernels import density_pallas as _dp
         from geomesa_tpu.kernels import pallas_kernels as pk
 
         d = setup["compact"]
         table = setup["table"]
-        pallas = config.DENSITY_PALLAS.to_bool() and pk.use_pallas()
-        mxu = config.DENSITY_MXU.to_bool()
-        cache = self.store.__dict__.setdefault("_density_ladder_cache", {})
+        pallas = pk.use_pallas()
+        caches = caches_of(self.store)
         key = (d["whash"], tuple(bbox), width, height, d["B"], d["C"],
-               pallas, mxu, _dp.max_dup(), _dm.tile_shape(),
-               self.store.uid, self.store.version, self._devkey())
-        hit = cache.get(key)
+               pallas, self.store.uid, self.store.version, self._devkey())
+        hit = caches.ladder.get(key)
         if hit is not None:
             return hit
         with tracing.span("scan.schedule") as sp:
-            boxes = self.store.__dict__.setdefault("_chunk_box_cache", {})
+            boxes = caches.chunk_boxes
             args = (d, table, table.keyspace, bbox, width, height)
             cand = _dm.pair_candidates(*args, _dp.TILE, _dp.TILE, boxes,
                                        self.store.version)
@@ -1340,10 +1288,10 @@ class Executor:
             C = int((d["valid"] > 0).sum())
             P = 0 if cand is None else int(cand["P"])
             kernel, sched, put = "scatter", None, ()
-            if cand is not None and pallas and P <= _dp.max_dup() * C:
+            if cand is not None and pallas and P <= _dp.MAX_DUP * C:
                 kernel, sched = "grouped", _dp.build_grouped(cand, d["B"])
                 put = ("sc", "row", "tile", "ox", "oy")
-            elif cand is not None and mxu:
+            elif cand is not None:
                 sched = _dm.build_pairs(*args, box_cache=boxes,
                                         version=self.store.version)
                 if sched is not None:
@@ -1352,10 +1300,7 @@ class Executor:
             for k in put:
                 sched[k] = self._put(sched[k])
             sp.set(kernel=kernel, pairs=P, chunks=C)
-        if len(cache) >= 64:
-            cache.clear()
-        hit = cache[key] = (kernel, sched, P, C)
-        return hit
+        return caches.ladder.put(key, (kernel, sched, P, C))
 
     def _kernel_span(self, site, setup=None, **attrs):
         """The ``scan.kernel`` span of one executor kernel dispatch, after
@@ -1681,7 +1626,7 @@ class Executor:
         table = self._table(plan)
         key = ("curve_pos", table.keyspace.name, self.store.version, level,
                tuple(block_window))
-        cache = self.store.__dict__.setdefault("_curve_pos_cache", {})
+        cache = caches_of(self.store).curve_positions
         hit = cache.get(key)
         if hit is not None:
             return hit
@@ -1726,11 +1671,7 @@ class Executor:
         if Bp != B:
             p0 = np.concatenate([p0, np.zeros(Bp - B, np.int32)])
             p1 = np.concatenate([p1, np.zeros(Bp - B, np.int32)])
-        out = (p0, p1, B, nx, ny)
-        if len(cache) >= 32:
-            cache.clear()
-        cache[key] = out
-        return out
+        return cache.put(key, (p0, p1, B, nx, ny))
 
     def density_curve_raw(self, plan: QueryPlan, level: int, block_window,
                           weight: Optional[str] = None):
@@ -2103,21 +2044,19 @@ class Executor:
                 self._note(p, kernel="hit")
         with tracing.span("scan.device_put", batch=len(plans)):
             dev_cols = table.device_columns(names, self._sharding())
-        wcache = self.store.__dict__.setdefault("_win_cache", {})
+        wcache = caches_of(self.store).device_windows
         # keyed by the window BYTES, not their hash: a collision here
         # would silently serve another batch's scan ranges, and equality
         # is the correctness contract (the [Mp, S, K] arrays are far
-        # smaller than the device windows the 64-entry cache holds)
+        # smaller than the device windows this cache holds)
         wkey = ("batch_win", site, self.store.uid, self.store.version,
                 K, Mp, bs["starts"].tobytes(), bs["ends"].tobytes(),
                 self._devkey())
         win = wcache.get(wkey)
         if win is None:
-            win = (self._put(bs["starts"]), self._put(bs["ends"]),
-                   self._put(bs["counts"]))
-            if len(wcache) >= 64:
-                wcache.clear()
-            wcache[wkey] = win
+            win = wcache.put(wkey, (self._put(bs["starts"]),
+                                    self._put(bs["ends"]),
+                                    self._put(bs["counts"])))
         for p in plans:
             self._note(p, scan="device-batch", batch=len(plans))
         # ONE observable unit of device work for the whole batch — the

@@ -2,10 +2,11 @@
 generator around one port, with vessels moored for days, served through
 ``GeoDataset``. Every density grid and ``Count();MinMax(SOG)`` equals the
 plain reference (``benchmarks/reference.py``) exactly, on each rung of the
-density ladder the CPU runs: the einsum pair kernel, scatter
-(``geomesa.density.mxu=false``) and the pallas grouped kernel in interpret
-mode. The ``scan.kernel`` span and the ``exec.density.kernel.<kernel>``
-counters name the rung that served, with the pair budget's P and C."""
+density ladder the CPU runs: the einsum pair kernel, scatter (a vessel's
+pings planned on the ``MMSI`` attribute index, which has no morton key)
+and the pallas grouped kernel in interpret mode. The ``scan.kernel`` span
+and the ``exec.density.kernel.<kernel>`` counters name the rung that
+served, with the pair budget's P and C."""
 
 import importlib.util
 import json
@@ -15,6 +16,8 @@ import numpy as np
 import pytest
 
 from geomesa_tpu import GeoDataset, config, metrics, tracing
+from geomesa_tpu.kernels import density_pallas
+from geomesa_tpu.planning.viewcache import caches_of
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SEED = 7
@@ -54,7 +57,7 @@ def ais():
     ds.create_schema("ais", cfg["spec"])
     ds.insert("ais", cols, fids=fids)
     ds.flush("ais")
-    return ds, data, gen, ref, cfg
+    return ds, dict(data, MMSI=np.asarray(cols["MMSI"])), gen, ref, cfg
 
 
 @pytest.fixture
@@ -136,15 +139,63 @@ def test_generator_piles_pings_at_berths(ais):
     assert np.unique(cell, return_counts=True)[1].max() > 5000
 
 
+def _serve_vessel(ds, data, ref, mmsi, bbox):
+    """Serve the density of one vessel's pings (``MMSI = '<id>'``) over
+    ``bbox``; check it against the reference's f32 binning of those pings,
+    rows outside the bbox clamped into the edge cells, and return its
+    kernel span attributes after checking the counters moved with it."""
+    before = _counters()
+    grid = ds.density("ais", f"MMSI = '{mmsi}'", bbox=bbox, width=512,
+                      height=512)
+    m = data["MMSI"] == mmsi
+    xmin, ymin, xmax, ymax = bbox
+    px = ref._bins(data["x"][m].astype(np.float32), xmin, xmax - xmin, 512)
+    py = ref._bins(data["y"][m].astype(np.float32), ymin, ymax - ymin, 512)
+    want = np.bincount(py.astype(np.int64) * 512 + px, minlength=512 * 512)
+    assert np.array_equal(np.asarray(grid, np.float64).reshape(-1),
+                          want.astype(np.float64)), mmsi
+    attrs = _kernel_attrs()
+    after = _counters()
+    assert {k: after[k] - before[k] for k in after} == \
+        {k: int(k == attrs["density_kernel"]) for k in after}
+    return attrs
+
+
+@pytest.fixture
+def fresh_ladder(ais):
+    """The store's ladder decisions emptied before and after the test:
+    its key holds only what the ladder observes, not what a test
+    patches."""
+    ladder = caches_of(ais[0]._store("ais")).ladder
+    ladder.clear()
+    yield
+    ladder.clear()
+
+
 @pytest.mark.parametrize("rung", ["mxu", "scatter"])
-def test_density_rung_equals_reference(served, rung):
-    """The CPU's own rungs: the einsum pair kernel where pallas does not
-    run, and scatter with the einsum rung off."""
-    with config.DENSITY_MXU.scoped(str(rung == "mxu").lower()):
+def test_density_rung_equals_reference(ais, served, rung):
+    """The CPU's own rungs, each where the ladder observes it applies: the
+    einsum pair kernel on the port views' z3 layout, where pallas does not
+    run; scatter on the ``MMSI`` attribute index, which has no morton key
+    to box the chunks with."""
+    if rung == "mxu":
         for view in VIEWS:
             attrs = served(view)
             assert attrs["density_kernel"] == rung, (view, attrs)
             assert attrs["chunks"] > 0 and attrs["pairs"] > 0
+        return
+    ds, data, gen, ref, cfg = ais
+    bbox = _request(gen, cfg, VIEWS[1])["bbox"]
+    x0, y0, x1, y1 = bbox
+    inside = ((data["x"] >= x0) & (data["x"] <= x1)
+              & (data["y"] >= y0) & (data["y"] <= y1))
+    ids, n = np.unique(data["MMSI"][inside], return_counts=True)
+    for mmsi in ids[np.argsort(-n, kind="stable")[:3]]:
+        st, _, plan = ds._plan("ais", f"MMSI = '{mmsi}'")
+        assert plan.index_name.startswith("attr:"), plan.index_name
+        attrs = _serve_vessel(ds, data, ref, mmsi, bbox)
+        assert attrs["density_kernel"] == rung, (mmsi, attrs)
+        assert attrs["chunks"] > 0 and attrs["pairs"] == 0
 
 
 def test_grouped_rung_equals_reference_in_interpret_mode(served,
@@ -162,8 +213,9 @@ def test_grouped_rung_equals_reference_in_interpret_mode(served,
 
 
 def test_fishing_ground_view_past_the_pair_budget_falls_back(ais, served,
-                                                             monkeypatch):
-    """A view whose chunks' key boxes span more than ``max.dup`` tiles
+                                                             monkeypatch,
+                                                             fresh_ladder):
+    """A view whose chunks' key boxes span more than ``MAX_DUP`` tiles
     each: pallas is available, yet the einsum rung serves, exactly; with
     the budget raised past its P, the grouped kernel serves it."""
     monkeypatch.setenv("GEOMESA_PALLAS_INTERPRET", "1")
@@ -172,8 +224,9 @@ def test_fishing_ground_view_past_the_pair_budget_falls_back(ais, served,
     attrs = served(VIEWS[-1])
     assert attrs["pairs"] > 4.0 * attrs["chunks"] > 0
     assert attrs["density_kernel"] == "mxu"
-    with config.DENSITY_PALLAS_MAX_DUP.scoped(str(attrs["pairs"])):
-        assert served(VIEWS[-1])["density_kernel"] == "grouped"
+    monkeypatch.setattr(density_pallas, "MAX_DUP", float(attrs["pairs"]))
+    caches_of(ais[0]._store("ais")).ladder.clear()
+    assert served(VIEWS[-1])["density_kernel"] == "grouped"
 
 
 @pytest.mark.parametrize("view", VIEWS[:4])

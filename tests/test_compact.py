@@ -8,6 +8,7 @@ import pytest
 from geomesa_tpu import GeoDataset
 from geomesa_tpu.filter.ecql import parse_iso_ms
 from geomesa_tpu.planning import executor as exmod
+from geomesa_tpu.planning.viewcache import caches_of
 
 
 @pytest.fixture
@@ -58,7 +59,8 @@ def force_compact():
 
 def _compact_was_used(ds, plan):
     st = ds._store("t")
-    return any(k[0] == "compact_win" for k in st.__dict__.get("_win_cache", {}))
+    return any(k[0] == "compact_win"
+               for k, _ in caches_of(st).device_windows.items())
 
 
 def test_compact_count_density_match_oracle(ds_data, force_compact):
@@ -135,8 +137,7 @@ def test_mxu_density_per_cell(ds_data, force_compact):
     ex = ds._executor(st)
     grid = ex.density(plan, bbox, W, H)
     # the ladder must have chosen the MXU pair list (proves the MXU path ran)
-    pc = st.__dict__.get("_density_ladder_cache", {})
-    assert any(v[0] == "mxu" for v in pc.values()), \
+    assert any(v[0] == "mxu" for _, v in caches_of(st).ladder.items()), \
         "MXU pair path did not engage"
     m = _oracle_mask(data)
     want = _f32_hist(data["geom__x"][m], data["geom__y"][m], bbox, W, H)

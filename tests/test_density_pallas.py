@@ -112,12 +112,14 @@ def test_grouped_weighted(ds_data, force_pallas):
 
 
 def test_grouped_matches_scatter_path(ds_data, force_pallas):
-    """Same query through the scatter path (pallas off) must agree exactly
-    on unweighted counts."""
+    """The grouped kernel agrees exactly on unweighted counts with the host
+    scatter (``np.add.at``) of the same query, and with the device scatter
+    of the padded layout."""
     ds, data = ds_data
     st, _, plan = ds._plan("t", ECQL)
     g1 = ds.density("t", ECQL, bbox=BBOX, width=256, height=256)
     assert _grouped_was_built(ds, plan, BBOX, 256, 256)
-    with config.DENSITY_PALLAS.scoped(False), config.DENSITY_MXU.scoped(False):
+    assert np.array_equal(g1.astype(np.float64), _oracle_grid(data, 256, 256))
+    with config.COMPACT_ENABLED.scoped(False):
         g2 = ds.density("t", ECQL, bbox=BBOX, width=256, height=256)
     assert np.array_equal(g1, g2)

@@ -11,6 +11,7 @@ import pytest
 
 from geomesa_tpu import GeoDataset
 from geomesa_tpu.filter.ecql import parse_iso_ms
+from geomesa_tpu.planning.viewcache import caches_of
 
 SPEC = "v:Double,dtg:Date,*geom:Point"
 
@@ -44,7 +45,7 @@ def test_bbox_edge_row_exact():
     assert sorted(fc.fids) == ["0", "2", "3"]
     # the band info was computed and found surviving uncertain rows
     st = ds._store("t")
-    infos = st.__dict__.get("_band_verdicts", {}).values()
+    infos = [v for _, v in caches_of(st).band_verdicts.items()]
     assert any(len(v) for v in infos)
 
 
@@ -58,8 +59,8 @@ def test_clean_data_keeps_device_path():
     y = ds._store("t")._all.columns["geom__y"]
     want = int(((x >= -100.5) & (x <= -80.5) & (y >= 30.5) & (y <= 40.5)).sum())
     assert ds.count("t", q) == want
-    verdicts = ds._store("t").__dict__.get("_band_verdicts", {})
-    assert verdicts and all(len(v) == 0 for v in verdicts.values())
+    verdicts = [v for _, v in caches_of(ds._store("t")).band_verdicts.items()]
+    assert verdicts and all(len(v) == 0 for v in verdicts)
 
 
 def test_float64_attribute_boundary():
